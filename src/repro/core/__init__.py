@@ -4,6 +4,7 @@ management for MoE serving."""
 from .controller import (CascadeController, StaticKController,
                          cascade_for_model)
 from .cost_model import (Hardware, Precision, TPU_V5E, RTX_6000_ADA,
+                         hardware_for_device_kind,
                          batch_iteration_time, expected_unique_experts,
                          expected_unique_experts_batch, iteration_bytes,
                          iteration_flops, iteration_time, draft_time,
@@ -32,6 +33,7 @@ __all__ = [
     "CascadeController", "StaticKController", "CascadeConfig",
     "SpeculationManager", "UtilityAnalyzer", "IterationRecord",
     "Hardware", "Precision", "TPU_V5E", "RTX_6000_ADA",
+    "hardware_for_device_kind",
     "expected_unique_experts",
     "expected_unique_experts_batch", "batch_iteration_time",
     "BatchCostOracle", "Calibration", "iteration_bytes", "iteration_flops",

@@ -35,11 +35,28 @@ class Hardware:
     hbm_bytes: float = 0.0
 
 
+# published peaks of one TPU v5e chip (Google Cloud documentation, "TPU v5e")
 TPU_V5E = Hardware("tpu-v5e", hbm_bw=819e9, peak_flops=197e12, ici_bw=50e9,
                    host_bw=32e9, hbm_bytes=16e9)
 # the paper's workstation GPU (RTX 6000 Ada): ~960 GB/s GDDR6, ~91 TFLOP/s fp16
 RTX_6000_ADA = Hardware("rtx-6000-ada", hbm_bw=960e9, peak_flops=91e12,
                         host_bw=32e9, hbm_bytes=48e9)
+
+#: `jax.Device.device_kind` -> the Hardware the cost model prices on it.
+#: A chip missing here is an error, never a default: planning one chip on
+#: another's peaks would misprice every grant and admission decision.
+HARDWARE_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for_device_kind(kind: str) -> Hardware:
+    """The cost model's Hardware for a device as JAX reports it; raises
+    ValueError for a kind the table does not list."""
+    try:
+        return HARDWARE_BY_DEVICE_KIND[kind]
+    except KeyError:
+        raise ValueError(
+            f"no cost-model Hardware for device kind {kind!r} (known: "
+            f"{sorted(HARDWARE_BY_DEVICE_KIND)})") from None
 
 
 @dataclass(frozen=True)

@@ -6,7 +6,7 @@ long spans from the prompt (n-gram heaven), code repeats idioms, math
 produces near-novel token streams (n-gram hostile). These generators build
 token-level analogues over a small vocabulary with the same qualitative
 structure, so a ~100M target model trained on them exhibits the paper's
-task-dependent acceptance rates *for real* (DESIGN.md §4)."""
+task-dependent acceptance rates *for real*."""
 
 from __future__ import annotations
 

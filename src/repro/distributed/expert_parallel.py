@@ -30,7 +30,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import moe as moe_mod
@@ -151,11 +150,11 @@ def make_expert_parallel_moe(cfg, mesh: Mesh, *, capacity_factor: float = 2.0):
                              "w_up": P(None, model_ax),
                              "w_down": P(model_ax, None)}
 
-    apply = shard_map(
+    apply = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(p_specs, P(data_ax, None)),
         out_specs=(P(data_ax, None),
                    {"lb_loss": P(), "unique_experts": P(data_ax),
                     "dropped": P(data_ax), "expert_idx": P(data_ax, None)}),
-        check_rep=False)
+        check_vma=False)
     return apply

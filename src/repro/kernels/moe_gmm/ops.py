@@ -8,9 +8,10 @@ Three backends, selected explicitly via `backend=`:
   "ref"       pure-jnp oracle
 
 `backend=None` auto-selects: "pallas" on TPU, else "ref" ("interpret" if
-`force_pallas=True`, kept for backward compatibility).  Tile-size kwargs
-are honored on both Pallas backends and are accepted-but-tiling-free on
-the ref path (the oracle has no tiles); unknown kwargs raise instead of
+`force_pallas=True`, kept for backward compatibility); "interpret" is
+refused on a TPU, so a chip run never times the interpreter.  Tile-size
+kwargs are honored on both Pallas backends and are accepted-but-tiling-free
+on the ref path (the oracle has no tiles); unknown kwargs raise instead of
 being silently swallowed."""
 
 from __future__ import annotations
@@ -47,6 +48,9 @@ def _resolve_backend(backend, force_pallas):
     if backend not in _BACKENDS:
         raise ValueError(f"unknown moe_gmm backend {backend!r}; "
                          f"expected one of {_BACKENDS}")
+    if backend == "interpret" and jax.default_backend() == "tpu":
+        raise ValueError("backend='interpret' on a TPU would run the Pallas "
+                         "interpreter in place of the compiled kernel")
     return backend
 
 

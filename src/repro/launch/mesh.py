@@ -21,13 +21,6 @@ def make_host_mesh():
 
 
 def make_abstract_mesh(shape, axes):
-    """Device-free mesh for sharding-rule checks (no real devices needed).
-
-    Absorbs the AbstractMesh constructor change: jax <= 0.4.35 took
-    `(shape_tuple, axis_names)` like Mesh; 0.4.36+ takes a single tuple of
-    `(name, size)` pairs (and 0.5+ re-adds a two-argument form)."""
+    """Device-free mesh for sharding-rule checks (no real devices needed)."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axes))
+    return AbstractMesh(tuple(shape), tuple(axes))

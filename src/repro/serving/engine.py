@@ -22,10 +22,10 @@ split (`cost_model.batch_iteration_time`). The batch-level cost driver is
 the *union* of experts the B spans activate — the paper's Fig. 2 effect
 compounding across requests.
 
-Timing source is pluggable: 'wall' uses the host clock (meaningful on real
-accelerators); 'model' uses the deterministic TPU-v5e data-movement cost
-model driven by the *measured* unique-expert activations of this iteration
-(DESIGN.md §4 — the honest CPU-container strategy)."""
+Timing source is pluggable: 'wall' uses the host clock (what a run on
+the chip reports); 'model' uses the deterministic data-movement cost model
+driven by the *measured* unique-expert activations of this iteration (the
+clock CPU tests run on, where a host timing says nothing about the chip)."""
 
 from __future__ import annotations
 
@@ -148,6 +148,15 @@ def _hidden_router_probe(cfg, params, moe_h, mask):
     logits = jnp.einsum("lbtd,lde->lbte", x, routers.astype(jnp.float32))
     _, idx = jax.lax.top_k(logits, cfg.experts_per_token)  # [L,B,T,k]
     return _layer_hist(cfg, idx, mask)
+
+
+def _with_finite_flags(out):
+    """decode_step output plus `aux["logits_finite"]` [B,T]: whether each
+    token's logits are all finite, reduced on the device so the host checks
+    B*T flags instead of scanning [B,T,V] logits every step."""
+    lo, cache, aux, staged = out
+    return (lo, cache, dict(aux, logits_finite=jnp.isfinite(lo).all(-1)),
+            staged)
 
 
 def _prefill_clock(cfg, hw, clock: str, n_tokens: int, wall: float, *,
@@ -620,20 +629,19 @@ class BatchedEngine:
                 self.placement.primary_shard_of, np.int32)
             n_sh = self.placement.n_shards
             self._decode = jax.jit(
-                lambda p, c, t, m, sid: T.decode_step(
+                lambda p, c, t, m, sid: _with_finite_flags(T.decode_step(
                     cfg, p, c, t, window=window, token_mask=m,
                     ep_shard_ids=sid, ep_n_shards=n_sh,
-                    moe_packed=self.packed, want_moe_h=want_h))
+                    moe_packed=self.packed, want_moe_h=want_h)))
         else:
             # unreplicated routing uses the static primary homes
             sid = (tuple(self.placement.primary_shard_of)
                    if self._ep else None)
             self._decode = jax.jit(
-                lambda p, c, t, m: T.decode_step(cfg, p, c, t, window=window,
-                                                 token_mask=m,
-                                                 ep_shard_ids=sid,
-                                                 moe_packed=self.packed,
-                                                 want_moe_h=want_h))
+                lambda p, c, t, m: _with_finite_flags(T.decode_step(
+                    cfg, p, c, t, window=window, token_mask=m,
+                    ep_shard_ids=sid, moe_packed=self.packed,
+                    want_moe_h=want_h)))
         #: speculation-guided prefetch probe (docs/offload.md): embed the
         #: packed span tokens and apply every MoE layer's router to them —
         #: a one-einsum approximation of the verification pass's routing
@@ -1119,6 +1127,9 @@ class BatchedEngine:
                 jnp.asarray(mask))
         lo = np.asarray(lo, np.float32)            # [B, T_max, V]
         wall_verify = time.perf_counter() - t1
+        if not np.asarray(aux["logits_finite"])[mask].all():
+            raise FloatingPointError(
+                f"non-finite logits in the pass of step {self._step_idx}")
         if self._hprobe is not None and "moe_h" in aux:
             # keep this pass's per-layer MoE inputs (+ their mask) as the
             # NEXT step's deep-layer nomination basis
@@ -1231,7 +1242,8 @@ class BatchedEngine:
             step_evictions = self.residency.evictions - ev0
         tokens_per_row = [int(mask[i].sum()) for i in range(b)]
         cost = cm.batch_iteration_time(
-            self.cfg, self.hw, tokens_per_row, list(lengths_before),
+            self.cfg, self.hw, tokens_per_row,
+            [int(n) for n in lengths_before],
             unique_experts=union,
             per_request_unique=(None if per_row is None else
                                 [per_row[i] if i in spans else 0.0

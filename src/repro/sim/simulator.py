@@ -11,7 +11,7 @@ uses) against full-size MoE configs, with:
 This is the substrate for the Fig. 4/5/8/13/15/16/18 reproductions. The
 end-to-end *real-model* path (examples/, tests) validates the same
 controller with genuine routing + genuine n-gram acceptance at small scale;
-the simulator extends it to the paper's model sizes (DESIGN.md §4)."""
+the simulator extends it to the paper's model sizes."""
 
 from __future__ import annotations
 

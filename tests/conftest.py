@@ -52,7 +52,7 @@ def copy_batch(rng, bs=16, period=COPY_PERIOD, seq=96, vocab=128):
 def trained_tiny_moe():
     """A tiny MoE trained on the periodic-copy task so that its greedy
     generations are genuinely n-gram-draftable (real acceptance, real
-    routing — the honest end-to-end path of DESIGN.md §4)."""
+    routing)."""
     import dataclasses
     import jax.numpy as jnp
     from repro.configs import get_config

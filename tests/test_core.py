@@ -15,7 +15,8 @@ except ImportError:  # deterministic in-repo fallback (requirements-dev.txt)
 from repro.core import (CascadeConfig, CascadeController, IterationRecord,
                         SpeculationManager, UtilityAnalyzer, TPU_V5E,
                         batch_iteration_time, expected_unique_experts,
-                        iteration_bytes, iteration_time)
+                        hardware_for_device_kind, iteration_bytes,
+                        iteration_time)
 from repro.core.manager import BASELINE, SET, TEST
 from repro.configs import get_config
 
@@ -221,6 +222,16 @@ def test_iteration_time_moe_cost_grows_with_inflight_tokens():
     assert t1 < t4 < t8
     # paper: 2-3x verification overhead in the K=3..7 range
     assert 1.5 < t8 / t1 < 4.0
+
+
+def test_hardware_for_device_kind():
+    """The chip's device_kind (as JAX reports a v5e) selects its peaks; a
+    kind the table does not list is refused, never priced as another."""
+    assert hardware_for_device_kind("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v4"):
+        hardware_for_device_kind("TPU v4")
+    with pytest.raises(ValueError, match="cpu"):
+        hardware_for_device_kind("cpu")
 
 
 def test_iteration_time_dense_cost_flat():

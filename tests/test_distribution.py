@@ -22,8 +22,7 @@ from repro.models import transformer as T
 @pytest.fixture(scope="module")
 def mesh44():
     # 4 "devices" arranged logically; on 1 real device jax.make_mesh fails,
-    # and an abstract mesh needs no devices at all. make_abstract_mesh
-    # absorbs the AbstractMesh constructor change across jax versions.
+    # and an abstract mesh needs no devices at all.
     from repro.launch.mesh import make_abstract_mesh
     return make_abstract_mesh((4, 4), ("data", "model"))
 

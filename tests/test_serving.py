@@ -11,6 +11,7 @@ except ImportError:  # deterministic in-repo fallback (requirements-dev.txt)
     from tests._hypothesis_compat import given, settings, st
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import CascadeController, StaticKController
 from repro.models import transformer as T
@@ -149,3 +150,58 @@ def test_engine_telemetry_breakdown(tiny_moe):
     assert bd["verify"] > 0 and bd["total"] >= bd["verify"]
     assert all(i.unique_experts >= cfg.experts_per_token
                for i in tel.iterations)
+
+
+# ===================================================================== #
+# The serving loop the launcher and the chip smoke run share
+# ===================================================================== #
+
+def test_serve_loop_finishes_every_request(tiny_moe):
+    """`launch.serve.serve` on the wall clock with chunked prefill: more
+    requests than rows, so rows retire and re-admit; every request ends
+    with its budget of in-vocabulary tokens, in submission order."""
+    from repro.core import TPU_V5E
+    from repro.launch.serve import (acceptance, mean_granted_k,
+                                    mixed_requests, serve)
+    cfg, params = tiny_moe
+    reqs = mixed_requests(cfg, 10, seed=3, max_new=6)
+    rep = serve(cfg, params, reqs, hw=TPU_V5E)
+    assert [r.telemetry.request_id for r in rep.results] == \
+        [r.request_id for r in reqs]
+    for r in rep.results:
+        assert len(r.tokens) == 6
+        assert all(0 <= t < cfg.vocab_size for t in r.tokens)
+    assert 0 < rep.first_token_s <= rep.wall_s
+    assert rep.scheduler.engine.clock == "wall"
+    assert 0.0 <= acceptance(rep.results) <= 1.0
+    assert mean_granted_k(rep.results) >= 0.0
+
+
+def test_engine_refuses_non_finite_logits(tiny_moe):
+    """A pass whose logits hold NaN must stop the engine, not be sampled."""
+    from repro.serving import BatchedEngine
+    cfg, params = tiny_moe
+    bad = dict(params, final_norm={"scale": jnp.full_like(
+        params["final_norm"]["scale"], jnp.nan)})
+    eng = BatchedEngine(cfg, bad, max_batch=1, max_len=128, chunk=8)
+    eng.join([5, 6, 7, 8], max_new=4)
+    with pytest.raises(FloatingPointError, match="non-finite"):
+        eng.step()
+
+
+def test_compile_cache_location(tmp_path, monkeypatch):
+    """Entry points keep JAX's persistent cache where
+    JAX_COMPILATION_CACHE_DIR says and set nothing themselves; without it,
+    at a fixed `.jax_cache` under the checkout."""
+    from repro.launch.serve import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "e"))
+        assert use_compile_cache(tmp_path) == str(tmp_path / "e")
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache(tmp_path) == str(tmp_path / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            tmp_path / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

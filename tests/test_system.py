@@ -92,8 +92,11 @@ def test_cascade_worst_case_bounded_real_engine(tiny_moe):
     # Cascade's measurement overhead — not more
     k3 = eng.generate(prompt, max_new=60, controller=StaticKController(3))
     assert k3.telemetry.tpot >= cas.telemetry.tpot * 0.90
-    # and Cascade must have actually enabled speculation (utility > 1)
-    assert cas.telemetry.iterations[-1].utility > 1.0
+    # and Cascade must have actually enabled speculation during the run
+    # (the stream's last iteration may fall back to K=0, so ask whether
+    # any iteration drafted with utility > 1, not whether the last did)
+    assert any(it.utility > 1.0 and it.k_drafted > 0
+               for it in cas.telemetry.iterations)
 
 
 # ===================================================================== #
