@@ -1,0 +1,15 @@
+"""Device-idle time, in ms per traced engine step, while the host ran the
+engine's stages before the pass (`engine.plan`, `engine.draft`,
+`engine.pack`, `engine.prefetch`, `engine.dispatch`): the idle time
+`bench.stagereduce` charges to those program spans, over the
+`engine.step` spans begun in the traced window. Nothing is read where
+the trace holds no program span."""
+
+from bench.stagereduce import PRE_PASS, for_run
+
+
+def read(run):
+    st = for_run(run)
+    if st is None or not st.engine_steps:
+        return None
+    return 1e3 * st.idle_in(PRE_PASS) / st.engine_steps

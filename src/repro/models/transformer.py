@@ -280,42 +280,47 @@ def _attn_block(cfg, p, x, lc, ctx, kind):
     mode = ctx["mode"]
     window = ctx["window"]
     seq_pos, rope_pos = ctx["seq_pos"], ctx["rope_pos"]
-    h = L.apply_norm(cfg, p["ln1"], x)
-    new_lc = {}
-    if cfg.use_mla:
-        if mode == "decode":
-            ckv_new, krope_new = mla_mod.latent_kv(cfg, p["attn"], h, seq_pos)
-            ckv = _write_ring(lc["ckv"], ckv_new, ctx)
-            krope = _write_ring(lc["krope"], krope_new, ctx)
-            out = mla_mod.mla_absorbed(cfg, p["attn"], h, seq_pos, ckv, krope,
-                                       ctx["cache_pos"], window=window)
-            new_lc.update(ckv=ckv, krope=krope)
+    with jax.named_scope("attention"):
+        h = L.apply_norm(cfg, p["ln1"], x)
+        new_lc = {}
+        if cfg.use_mla:
+            if mode == "decode":
+                ckv_new, krope_new = mla_mod.latent_kv(cfg, p["attn"], h,
+                                                       seq_pos)
+                ckv = _write_ring(lc["ckv"], ckv_new, ctx)
+                krope = _write_ring(lc["krope"], krope_new, ctx)
+                out = mla_mod.mla_absorbed(cfg, p["attn"], h, seq_pos, ckv,
+                                           krope, ctx["cache_pos"],
+                                           window=window)
+                new_lc.update(ckv=ckv, krope=krope)
+            else:
+                out, (ckv_new, krope_new) = mla_mod.mla_full(
+                    cfg, p["attn"], h, seq_pos)
+                if mode == "prefill":
+                    t_w = ctx["t_w"]
+                    new_lc["ckv"] = _write_ring(lc["ckv"], ckv_new[:, -t_w:],
+                                                ctx)
+                    new_lc["krope"] = _write_ring(lc["krope"],
+                                                  krope_new[:, -t_w:], ctx)
         else:
-            out, (ckv_new, krope_new) = mla_mod.mla_full(cfg, p["attn"], h, seq_pos)
-            if mode == "prefill":
-                t_w = ctx["t_w"]
-                new_lc["ckv"] = _write_ring(lc["ckv"], ckv_new[:, -t_w:],
-                                            ctx)
-                new_lc["krope"] = _write_ring(lc["krope"],
-                                              krope_new[:, -t_w:], ctx)
-    else:
-        q, k, v = attn_mod.qkv(cfg, p["attn"], h, rope_pos)
-        if mode == "decode":
-            kb = _write_ring(lc["k"], k, ctx)
-            vb = _write_ring(lc["v"], v, ctx)
-            out = attn_mod.attend(q, kb.astype(q.dtype), vb.astype(q.dtype),
-                                  seq_pos, ctx["cache_pos"],
-                                  window=window, causal=True)
-            new_lc.update(k=kb, v=vb)
-        else:
-            out = attn_mod.attend(q, k, v, seq_pos, seq_pos,
-                                  window=window, causal=True)
-            if mode == "prefill":
-                t_w = ctx["t_w"]
-                new_lc["k"] = _write_ring(lc["k"], k[:, -t_w:], ctx)
-                new_lc["v"] = _write_ring(lc["v"], v[:, -t_w:], ctx)
-        b, t = out.shape[:2]
-        out = out.reshape(b, t, -1) @ p["attn"]["wo"]
+            q, k, v = attn_mod.qkv(cfg, p["attn"], h, rope_pos)
+            if mode == "decode":
+                kb = _write_ring(lc["k"], k, ctx)
+                vb = _write_ring(lc["v"], v, ctx)
+                out = attn_mod.attend(q, kb.astype(q.dtype),
+                                      vb.astype(q.dtype), seq_pos,
+                                      ctx["cache_pos"], window=window,
+                                      causal=True)
+                new_lc.update(k=kb, v=vb)
+            else:
+                out = attn_mod.attend(q, k, v, seq_pos, seq_pos,
+                                      window=window, causal=True)
+                if mode == "prefill":
+                    t_w = ctx["t_w"]
+                    new_lc["k"] = _write_ring(lc["k"], k[:, -t_w:], ctx)
+                    new_lc["v"] = _write_ring(lc["v"], v[:, -t_w:], ctx)
+            b, t = out.shape[:2]
+            out = out.reshape(b, t, -1) @ p["attn"]["wo"]
     x = x + out
 
     if kind == "X":  # cross-attention to (stub) encoder states
@@ -338,9 +343,11 @@ def _attn_block(cfg, p, x, lc, ctx, kind):
     aux = {}
     if cfg.is_moe:
         b, t, d = h2.shape
-        y2d, moe_aux = moe_mod.apply_moe(cfg, p["moe"], h2.reshape(b * t, d),
-                                         capacity_policy=ctx["moe_policy"],
-                                         packed=ctx.get("moe_packed", False))
+        with jax.named_scope("moe_ffn"):
+            y2d, moe_aux = moe_mod.apply_moe(
+                cfg, p["moe"], h2.reshape(b * t, d),
+                capacity_policy=ctx["moe_policy"],
+                packed=ctx.get("moe_packed", False))
         x = x + y2d.reshape(b, t, d)
         aux["lb_loss"] = moe_aux["lb_loss"]
         aux["unique_experts"] = moe_aux["unique_experts"]
@@ -622,8 +629,9 @@ def _forward(cfg, params, tokens, *, embeds, cache, mode, seq_pos, rope_pos,
     uniform = len(set(cfg.layer_kinds())) == 1
     run = _run_uniform if uniform else _run_pattern
     x, ys = run(cfg, params, x, cache, ctx)
-    x = L.apply_norm(cfg, params["final_norm"], x)
-    logits = L.unembed(cfg, params["embed"], x)
+    with jax.named_scope("lm_head"):
+        x = L.apply_norm(cfg, params["final_norm"], x)
+        logits = L.unembed(cfg, params["embed"], x)
 
     aux = {}
     if "aux" in ys:

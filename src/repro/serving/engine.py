@@ -38,6 +38,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import cost_model as cm
 from repro.core.controller import CascadeController, StaticKController
@@ -755,91 +756,93 @@ class BatchedEngine:
         ANY co-scheduled row that would push this request past its bound
         are denied) and is handed to the request's own Cascade config so
         the per-request trial gate enforces the same bound."""
-        if not prompt:
-            raise ValueError("empty prompt — nothing to prefill")
-        if len(prompt) >= self.max_len:
-            raise ValueError(f"prompt of {len(prompt)} tokens cannot fit a "
-                             f"max_len={self.max_len} cache row")
-        free = self.free_slots
-        if not free:
-            raise RuntimeError("no free slot — retire a request first")
-        idx = free[0]
-        self._shard_profiles.pop(idx, None)  # fresh row, fresh profile
-        controller = controller or self.controller_factory()
-        if slo is not None and slo.tpot is not None:
-            # the per-request FSM shares the bound: its measured trial
-            # gate (manager._slo_allows) and the planner's predicted grant
-            # constraint then enforce the SAME objective at both levels.
-            # An explicit CascadeConfig.slo_tpot wins over the request's,
-            # and the caller's config object is never mutated (a factory
-            # may hand the same tuned config to every controller —
-            # install the bound on a per-request replacement instead).
-            ccfg = getattr(controller, "config", None)
-            if (dataclasses.is_dataclass(ccfg)
-                    and getattr(ccfg, "slo_tpot", 0) is None):
-                bound_cfg = dataclasses.replace(ccfg, slo_tpot=slo.tpot)
-                controller.config = bound_cfg
-                mgr = getattr(controller, "manager", None)
-                if mgr is not None and getattr(mgr, "cfg", None) is ccfg:
-                    mgr.cfg = bound_cfg
-        drafter = self.drafter_factory()
-        drafter.reset()
-        # the first request consumes exactly the legacy engine's rng stream
-        # (bit-identical batch=1 behaviour); later requests get their own
-        n = self._req_counter
-        rng = (np.random.default_rng(self.seed) if n == 0
-               else np.random.default_rng([self.seed, n]))
-        self._req_counter += 1
+        with TraceAnnotation("engine.join"):
+            if not prompt:
+                raise ValueError("empty prompt — nothing to prefill")
+            if len(prompt) >= self.max_len:
+                raise ValueError(f"prompt of {len(prompt)} tokens cannot fit "
+                                 f"a max_len={self.max_len} cache row")
+            free = self.free_slots
+            if not free:
+                raise RuntimeError("no free slot — retire a request first")
+            idx = free[0]
+            self._shard_profiles.pop(idx, None)  # fresh row, fresh profile
+            controller = controller or self.controller_factory()
+            if slo is not None and slo.tpot is not None:
+                # the per-request FSM shares the bound: its measured trial
+                # gate (manager._slo_allows) and the planner's predicted grant
+                # constraint then enforce the SAME objective at both levels.
+                # An explicit CascadeConfig.slo_tpot wins over the request's,
+                # and the caller's config object is never mutated (a factory
+                # may hand the same tuned config to every controller —
+                # install the bound on a per-request replacement instead).
+                ccfg = getattr(controller, "config", None)
+                if (dataclasses.is_dataclass(ccfg)
+                        and getattr(ccfg, "slo_tpot", 0) is None):
+                    bound_cfg = dataclasses.replace(ccfg, slo_tpot=slo.tpot)
+                    controller.config = bound_cfg
+                    mgr = getattr(controller, "manager", None)
+                    if mgr is not None and getattr(mgr, "cfg", None) is ccfg:
+                        mgr.cfg = bound_cfg
+            drafter = self.drafter_factory()
+            drafter.reset()
+            # the first request consumes exactly the legacy engine's rng stream
+            # (bit-identical batch=1 behaviour); later requests get their own
+            n = self._req_counter
+            rng = (np.random.default_rng(self.seed) if n == 0
+                   else np.random.default_rng([self.seed, n]))
+            self._req_counter += 1
 
-        t_submit = self.now if submit_time is None else float(submit_time)
-        tel = RequestTelemetry(request_id=request_id, task=task,
-                               prompt_len=len(prompt))
-        if slo is not None:
-            tel.tier = slo.tier
-            tel.slo_tpot = slo.tpot
-            tel.slo_ttft = slo.ttft
+            t_submit = self.now if submit_time is None else float(submit_time)
+            tel = RequestTelemetry(request_id=request_id, task=task,
+                                   prompt_len=len(prompt))
+            if slo is not None:
+                tel.tier = slo.tier
+                tel.slo_tpot = slo.tpot
+                tel.slo_ttft = slo.ttft
 
-        if self.chunk > 0 and enc_out is None:
-            # non-blocking admission: no forward pass here; the row's cache
-            # is empty (lengths[idx] == 0) and fills chunk by chunk
-            self.slots[idx] = _Slot(
-                index=idx, request_id=request_id, task=task,
-                max_new=max_new, stop_token=stop_token,
-                controller=controller, drafter=drafter, rng=rng, tel=tel,
-                history=list(prompt), out=[], last_tok=-1,
-                phase="prefill", prompt=list(prompt),
-                t_submit=t_submit, seq=n, slo=slo)
+            if self.chunk > 0 and enc_out is None:
+                # non-blocking admission: no forward pass here; the row's cache
+                # is empty (lengths[idx] == 0) and fills chunk by chunk
+                self.slots[idx] = _Slot(
+                    index=idx, request_id=request_id, task=task,
+                    max_new=max_new, stop_token=stop_token,
+                    controller=controller, drafter=drafter, rng=rng, tel=tel,
+                    history=list(prompt), out=[], last_tok=-1,
+                    phase="prefill", prompt=list(prompt),
+                    t_submit=t_submit, seq=n, slo=slo)
+                self._joined_since_step += 1
+                return idx
+
+            row = T.init_cache(self.cfg, 1, self.max_len, window=self.window)
+            toks = jnp.asarray(prompt, jnp.int32)[None, :]
+            t0 = time.perf_counter()
+            logits, row, _ = self._prefill(self.params, toks, row, enc_out)
+            logits = np.asarray(logits[0, -1], np.float32)
+            wall_prefill = time.perf_counter() - t0
+            tel.t_prefill = _prefill_clock(self.cfg, self.hw, self.clock,
+                                           len(prompt), wall_prefill,
+                                           affinity=self.affinity,
+                                           window=self.window,
+                                           precision=self.precision)
+            tel.t_queue = max(self.now - t_submit, 0.0)
+            tel.ttft = tel.t_queue + tel.t_prefill
+            # blocking: everyone waits out the prefill
+            self.now += tel.t_prefill
+            self.cache = T.write_cache_row(self.cache, idx, row)
+
+            first = _sample_logits(rng, logits, self.temperature)
+            slot = _Slot(
+                index=idx, request_id=request_id, task=task, max_new=max_new,
+                stop_token=stop_token, controller=controller, drafter=drafter,
+                rng=rng, tel=tel, history=list(prompt) + [first], out=[first],
+                last_tok=first, t_submit=t_submit, seq=n, slo=slo)
+            self._maybe_finish(slot,
+                               stopped=stop_token is not None
+                               and first == stop_token)
+            self.slots[idx] = slot
             self._joined_since_step += 1
             return idx
-
-        row = T.init_cache(self.cfg, 1, self.max_len, window=self.window)
-        toks = jnp.asarray(prompt, jnp.int32)[None, :]
-        t0 = time.perf_counter()
-        logits, row, _ = self._prefill(self.params, toks, row, enc_out)
-        logits = np.asarray(logits[0, -1], np.float32)
-        wall_prefill = time.perf_counter() - t0
-        tel.t_prefill = _prefill_clock(self.cfg, self.hw, self.clock,
-                                       len(prompt), wall_prefill,
-                                       affinity=self.affinity,
-                                       window=self.window,
-                                       precision=self.precision)
-        tel.t_queue = max(self.now - t_submit, 0.0)
-        tel.ttft = tel.t_queue + tel.t_prefill
-        self.now += tel.t_prefill  # blocking: everyone waits out the prefill
-        self.cache = T.write_cache_row(self.cache, idx, row)
-
-        first = _sample_logits(rng, logits, self.temperature)
-        slot = _Slot(
-            index=idx, request_id=request_id, task=task, max_new=max_new,
-            stop_token=stop_token, controller=controller, drafter=drafter,
-            rng=rng, tel=tel, history=list(prompt) + [first], out=[first],
-            last_tok=first, t_submit=t_submit, seq=n, slo=slo)
-        self._maybe_finish(slot,
-                           stopped=stop_token is not None
-                           and first == stop_token)
-        self.slots[idx] = slot
-        self._joined_since_step += 1
-        return idx
 
     def _attr_share(self, cost: dict, i: int, wall_verify: float,
                     occupancy: int) -> float:
@@ -909,98 +912,108 @@ class BatchedEngine:
         every request sharing the pass — the paper's Fig. 2 effect now
         includes admission. Returns {slot: emitted tokens}; empty when
         nothing is live."""
-        active = self.active_slots
-        if not active:
-            return {}
-        b = self.max_batch
-        slots = self.slots
-        lengths_before = np.asarray(self.cache["lengths"])
-        decode_rows = [i for i in active if slots[i].phase == "decode"]
-        prefill_rows = sorted(
-            (i for i in active if slots[i].phase == "prefill"),
-            key=lambda i: slots[i].seq)
+        with TraceAnnotation("engine.step", step=self._step_idx):
+            return self._step()
 
-        # EVERY non-done row of the padded pass gets T_max ring-slot writes
-        # starting at its own length (padding writes are rolled back, but
-        # they land first) — including rows whose prefill was NOT admitted
-        # this step. Cap this step's span lengths so no such row's padded
-        # writes can wrap past its cache end, and so a windowed ring's
-        # contiguous write stays inside its SPEC_PAD spill slots. Under
-        # chunked admission the cap is floored to a power of two, keeping
-        # the bucketed [B, T] trace shapes a small fixed set even when a
-        # long-running row squeezes the room step by step.
-        room_min = min(self.max_len - int(lengths_before[i])
-                       for i in active)
-        if self.window:
-            room_min = min(room_min, T.SPEC_PAD)
-        if self.chunk > 0 and room_min > 0:
-            room_min = 1 << (room_min.bit_length() - 1)
+    def _step(self) -> dict:
+        """`step` inside its span: each stage in a span of its own."""
+        with TraceAnnotation("engine.plan"):
+            active = self.active_slots
+            if not active:
+                return {}
+            b = self.max_batch
+            slots = self.slots
+            lengths_before = np.asarray(self.cache["lengths"])
+            decode_rows = [i for i in active if slots[i].phase == "decode"]
+            prefill_rows = sorted(
+                (i for i in active if slots[i].phase == "prefill"),
+                key=lambda i: slots[i].seq)
 
-        # 0. admission policy: pack pending prefill chunks FIFO under the
-        # per-step token budget. The head-of-queue chunk always runs (no
-        # starvation under a tiny budget); later chunks wait their turn.
-        # The capacity cap applies before the budget debit, so a capped
-        # head chunk does not eat budget it cannot use.
-        chunk_plan: dict = {}
-        budget = self.max_prefill_tokens_per_step
-        for i in prefill_rows:
-            s = slots[i]
-            n = min(self.chunk, len(s.prompt) - s.prefill_pos, room_min)
-            if n <= 0:
-                continue
-            if chunk_plan and n > budget:
-                break
-            chunk_plan[i] = n
-            budget -= n
-            if not s.queue_seen:
-                s.tel.t_queue = max(self.now - s.t_submit, 0.0)
-                s.queue_seen = True
-        if not decode_rows and not chunk_plan:
-            return {}
+            # EVERY non-done row of the padded pass gets T_max ring-slot
+            # writes starting at its own length (padding writes are rolled
+            # back, but they land first) — including rows whose prefill was
+            # NOT admitted this step. Cap this step's span lengths so no
+            # such row's padded writes can wrap past its cache end, and so a
+            # windowed ring's contiguous write stays inside its SPEC_PAD
+            # spill slots. Under chunked admission the cap is floored to a
+            # power of two, keeping the bucketed [B, T] trace shapes a small
+            # fixed set even when a long-running row squeezes the room step
+            # by step.
+            room_min = min(self.max_len - int(lengths_before[i])
+                           for i in active)
+            if self.window:
+                room_min = min(room_min, T.SPEC_PAD)
+            if self.chunk > 0 and room_min > 0:
+                room_min = 1 << (room_min.bit_length() - 1)
 
-        # 1. joint speculation planning + per-request drafting: each
-        # request's controller asks (the Cascade FSM still explores and
-        # disables per request), the planner grants {K_i} jointly — greedy
-        # marginal-utility water-filling over the shared pass, with TEST
-        # phases staggered to one trial per step (docs/planner.md). Under
-        # policy="independent", and always at B=1, grants == asks exactly.
-        plan = self.planner.plan(
-            {i: slots[i].controller for i in decode_rows},
-            [int(n) for n in lengths_before],
-            prefill_tokens=chunk_plan,
-            shard_weights=({i: self._shard_profiles[i] for i in decode_rows
-                            if i in self._shard_profiles}
-                           if self._ep else None),
-            slos={i: slots[i].slo for i in decode_rows
-                  if slots[i].slo is not None})
-        k_req, drafts, draft_probs, wall_draft = {}, {}, {}, {}
-        for i in decode_rows:
-            s = slots[i]
-            k_req[i] = plan.decisions[i].requested
-            t0 = time.perf_counter()
-            drafts[i], draft_probs[i] = s.drafter.propose(
-                s.history, plan.decisions[i].granted, rng=s.rng)
-            wall_draft[i] = time.perf_counter() - t0
-            if len(drafts[i]) > room_min - 1:  # span = 1 + drafts
-                drafts[i] = drafts[i][:max(room_min - 1, 0)]
-                if draft_probs[i] is not None:
-                    draft_probs[i] = draft_probs[i][:len(drafts[i])]
+            # 0. admission policy: pack pending prefill chunks FIFO under the
+            # per-step token budget. The head-of-queue chunk always runs (no
+            # starvation under a tiny budget); later chunks wait their turn.
+            # The capacity cap applies before the budget debit, so a capped
+            # head chunk does not eat budget it cannot use.
+            chunk_plan: dict = {}
+            budget = self.max_prefill_tokens_per_step
+            for i in prefill_rows:
+                s = slots[i]
+                n = min(self.chunk, len(s.prompt) - s.prefill_pos, room_min)
+                if n <= 0:
+                    continue
+                if chunk_plan and n > budget:
+                    break
+                chunk_plan[i] = n
+                budget -= n
+                if not s.queue_seen:
+                    s.tel.t_queue = max(self.now - s.t_submit, 0.0)
+                    s.queue_seen = True
+            if not decode_rows and not chunk_plan:
+                return {}
 
-        # 2. pack ragged [1 + K_i] decode spans and prefill chunks into one
-        # padded batch; bucket T to a power of two under chunked admission
-        # so jit traces are reused across prompt/chunk lengths
-        spans = {i: [slots[i].last_tok] + drafts[i] for i in decode_rows}
-        for i, n in chunk_plan.items():
-            s = slots[i]
-            spans[i] = s.prompt[s.prefill_pos:s.prefill_pos + n]
-        t_max = max(len(sp) for sp in spans.values())
-        if self.chunk > 0:
-            t_max = min(T.bucket_length(t_max), room_min)
-        toks = np.zeros((b, t_max), np.int32)
-        mask = np.zeros((b, t_max), bool)
-        for i, span in spans.items():
-            toks[i, :len(span)] = span
-            mask[i, :len(span)] = True
+            # 1. joint speculation planning + per-request drafting: each
+            # request's controller asks (the Cascade FSM still explores and
+            # disables per request), the planner grants {K_i} jointly —
+            # greedy marginal-utility water-filling over the shared pass,
+            # with TEST phases staggered to one trial per step
+            # (docs/planner.md). Under policy="independent", and always at
+            # B=1, grants == asks exactly.
+            plan = self.planner.plan(
+                {i: slots[i].controller for i in decode_rows},
+                [int(n) for n in lengths_before],
+                prefill_tokens=chunk_plan,
+                shard_weights=({i: self._shard_profiles[i] for i in decode_rows
+                                if i in self._shard_profiles}
+                               if self._ep else None),
+                slos={i: slots[i].slo for i in decode_rows
+                      if slots[i].slo is not None})
+        with TraceAnnotation("engine.draft"):
+            k_req, drafts, draft_probs, wall_draft = {}, {}, {}, {}
+            for i in decode_rows:
+                s = slots[i]
+                k_req[i] = plan.decisions[i].requested
+                t0 = time.perf_counter()
+                drafts[i], draft_probs[i] = s.drafter.propose(
+                    s.history, plan.decisions[i].granted, rng=s.rng)
+                wall_draft[i] = time.perf_counter() - t0
+                if len(drafts[i]) > room_min - 1:  # span = 1 + drafts
+                    drafts[i] = drafts[i][:max(room_min - 1, 0)]
+                    if draft_probs[i] is not None:
+                        draft_probs[i] = draft_probs[i][:len(drafts[i])]
+
+        with TraceAnnotation("engine.pack"):
+            # 2. pack ragged [1 + K_i] decode spans and prefill chunks into one
+            # padded batch; bucket T to a power of two under chunked admission
+            # so jit traces are reused across prompt/chunk lengths
+            spans = {i: [slots[i].last_tok] + drafts[i] for i in decode_rows}
+            for i, n in chunk_plan.items():
+                s = slots[i]
+                spans[i] = s.prompt[s.prefill_pos:s.prefill_pos + n]
+            t_max = max(len(sp) for sp in spans.values())
+            if self.chunk > 0:
+                t_max = min(T.bucket_length(t_max), room_min)
+            toks = np.zeros((b, t_max), np.int32)
+            mask = np.zeros((b, t_max), bool)
+            for i, span in spans.items():
+                toks[i, :len(span)] = span
+                mask[i, :len(span)] = True
 
         # 2b. speculation-guided prefetch (docs/offload.md): this step's
         # spans are a window into the verification union — route them
@@ -1019,396 +1032,406 @@ class BatchedEngine:
         staged_counts = None          # [S][L] per-layer staged counts
         fetch_hide = 0.0              # scalar window, or [L] schedule
         if self._offload:
-            base_hide = 0.0
-            if self.prefetch:
-                # the model-clock draft+sample window of this step — what
-                # a prefetched byte can hide behind (same expressions as
-                # stage 7's t_overhead, known here because K_i are fixed)
-                base_hide = max(
-                    (cm.draft_time(self.hw, len(drafts[i]),
-                                   slots[i].drafter.active_params,
-                                   precision=self.drafter_precision)
-                     + cm.sample_time(len(drafts[i]))
-                     for i in decode_rows), default=0.0)
-            if self._layered:
-                # layered streaming: layer l's staged fetches additionally
-                # hide behind the compute of layers < l in THIS pass (the
-                # planner's predicted base pass is the compute estimate —
-                # priced for the current batch composition, so membership
-                # churn reprices the window the same step it happens)...
-                if self.prefetch and self.double_buffer:
-                    # ...and, double-buffered, behind the tail of the
-                    # PREVIOUS pass that ran after its last MoE layer
-                    # consumed weights — the link was idle there
-                    base_hide += (1.0 - self._hide_fracs[-1]) \
-                        * self._last_t_iter
-                fetch_hide = cm.fetch_hide_schedule(self.cfg, base_hide,
-                                                    plan.t_base)
-                n_l = self.residency.n_unit_layers
-                staged_counts = [[0] * n_l
-                                 for _ in range(self.residency.n_shards)]
-                if self._probe is not None:
-                    pred = np.asarray(self._probe(self.params,
-                                                  jnp.asarray(toks),
-                                                  jnp.asarray(mask)))
-                    if self._last_moe_h is not None:
-                        # deep layers nominate from the PREVIOUS pass's
-                        # per-layer hidden states — layer l's router over
-                        # layer l's actual inputs, not the embedding
-                        # (layer 0 keeps the current spans' embed probe:
-                        # its routing input IS close to the embedding)
-                        hp = np.asarray(self._hprobe(self.params,
-                                                     self._last_moe_h,
-                                                     self._last_mask))
-                        pred = np.concatenate([pred[:1], hp[1:]], axis=0)
-                    # nominate layer-by-layer in pipeline order —
-                    # most-confident first within a layer, exactly the
-                    # order the link drains and the cumulative staged
-                    # cap credits (fetch_time_layered)
-                    for lyr in range(n_l):
-                        row = pred[lyr]
-                        nominated = sorted(
-                            ((lyr, int(e)) for e in np.nonzero(row)[0]
-                             if row[e] >= self.prefetch_min_count),
-                            key=lambda u: (-int(row[u[1]]), u[1]))
-                        pf = self.residency.fetch(nominated,
-                                                  self._step_idx,
-                                                  stage=True)
-                        for s_i, c in enumerate(pf["per_shard"]):
-                            staged_counts[s_i][lyr] = c
-            else:
-                fetch_hide = base_hide
+            with TraceAnnotation("engine.prefetch"):
+                base_hide = 0.0
                 if self.prefetch:
-                    # ... plus the dense compute ahead of the first MoE
-                    # layer: the DMA issued now keeps streaming while
-                    # embed + leading layers run, and the weights are
-                    # only needed when that layer routes (the planner's
-                    # predicted base pass for THIS batch composition is
-                    # the compute estimate — the previous pass's t_iter
-                    # overstates the window right after rows retire)
-                    fetch_hide += self._pre_moe_frac * plan.t_base
-                if self._probe is not None:
-                    pred = np.asarray(self._probe(self.params,
-                                                  jnp.asarray(toks),
-                                                  jnp.asarray(mask))
-                                      ).sum(axis=0)        # [L,E] -> [E]
-                    # most-confident first: experts routed by more
-                    # predicted (token, layer) slots stage before marginal
-                    # ones (the ordering the min-count filter and hide
-                    # window reward)
-                    nominated = sorted(
-                        (int(e) for e in np.nonzero(pred)[0]
-                         if pred[e] >= self.prefetch_min_count),
-                        key=lambda e: (-int(pred[e]), e))
-                    pf = self.residency.fetch(nominated, self._step_idx,
-                                              stage=True)
-                    prefetch_counts = pf["per_shard"]
-                    # honest hide: the draft+sample window only hides
-                    # bytes that were actually prefetched during it —
-                    # demand misses are discovered at pass time and can
-                    # never hide, so cap the credit at the prefetched
-                    # fetch time (the layered path applies the same cap
-                    # per layer inside fetch_time_layered, from
-                    # staged_counts)
-                    fetch_hide = min(
-                        fetch_hide,
-                        max(prefetch_counts) * self.residency.expert_bytes
-                        / self.hw.host_bw)
-
-        # 3. shared verification pass
-        t1 = time.perf_counter()
-        if self._replica_routes is not None:
-            lo, new_cache, aux, staged = self._decode(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(mask), jnp.asarray(self._replica_routes))
-        else:
-            lo, new_cache, aux, staged = self._decode(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(mask))
-        lo = np.asarray(lo, np.float32)            # [B, T_max, V]
-        wall_verify = time.perf_counter() - t1
-        if not np.asarray(aux["logits_finite"])[mask].all():
-            raise FloatingPointError(
-                f"non-finite logits in the pass of step {self._step_idx}")
-        if self._hprobe is not None and "moe_h" in aux:
-            # keep this pass's per-layer MoE inputs (+ their mask) as the
-            # NEXT step's deep-layer nomination basis
-            self._last_moe_h = aux["moe_h"]        # [L, B, T, d] (device)
-            self._last_mask = jnp.asarray(mask)
-
-        # 4. per-row rejection sampling (decode rows only — prefill chunks
-        # commit all their real tokens, nothing to verify)
-        results, wall_sample = {}, {}
-        for i in decode_rows:
-            s = slots[i]
-            n_i = 1 + len(drafts[i])
-            t2 = time.perf_counter()
-            if self.temperature <= 0:
-                results[i] = greedy_verify(lo[i, :n_i], drafts[i])
-            else:
-                probs = np.asarray(logits_to_probs(
-                    jnp.asarray(lo[i, :n_i]), self.temperature))
-                results[i] = rejection_sample(s.rng, probs, drafts[i],
-                                              draft_probs[i])
-            wall_sample[i] = time.perf_counter() - t2
-
-        # 5. vectorized per-row rollback (idle rows keep length unchanged;
-        # prefill rows keep their whole real chunk, dropping the padding)
-        n_keep = np.zeros((b,), np.int32)
-        for i in decode_rows:
-            n_keep[i] = 1 + results[i].n_accepted
-        for i, n in chunk_plan.items():
-            n_keep[i] = n
-        self.cache = T.rollback_cache(self.cfg, new_cache, staged,
-                                      jnp.asarray(n_keep),
-                                      jnp.asarray(lengths_before))
-
-        # 6. batch-aware cost accounting + marginal attribution
-        union = per_row = shard_mean = row_shard = None
-        if self.cfg.is_moe and "unique_experts" in aux:
-            # mean over *layers* of the masked per-layer union [L]. (The EP
-            # apply path used to land its per-source-shard counts on this
-            # key, and a bare np.mean folded them into a scalar that was
-            # neither the union nor the gating shard; the union is now
-            # recomputed from the gathered expert ids upstream, and the
-            # per-shard view arrives separately below.)
-            union = float(np.mean(np.asarray(aux["unique_experts"])))
-        if self.cfg.is_moe and "unique_experts_row" in aux:
-            per_row = np.mean(np.asarray(aux["unique_experts_row"],
-                                         np.float64), axis=0)   # [B]
-        if self._ep and "unique_experts_shard" in aux:
-            shard_mean = np.mean(np.asarray(aux["unique_experts_shard"],
-                                            np.float64), axis=0)   # [S]
-            row_shard = np.mean(np.asarray(aux["unique_experts_row_shard"],
-                                           np.float64), axis=0)    # [B,S]
-        # residency bookkeeping: classify the pass's ACTUAL activated
-        # host-tier experts into prefetch hits and demand misses, fetch
-        # the misses (discovered too late to hide), evict-and-admit, and
-        # price the pass with the measured per-shard fetch counts
-        per_shard_miss = None
-        n_hits = n_miss = step_evictions = 0
-        step_fetch_bytes = 0.0
-        hit_by_layer = miss_by_layer = ()
-        if self._offload:
-            ev0 = self.residency.evictions
-            if self._layered:
-                # per-(layer, expert) units: each MoE layer's activated
-                # slices classify and demand-fetch independently, in
-                # pipeline (layer) order — the measured [S][L] counts the
-                # layered pricing consumes
-                n_l = self.residency.n_unit_layers
-                units = []
-                if "experts_active" in aux:
-                    act = np.asarray(aux["experts_active"])  # [L, E]
-                    units = [(int(l), int(e))
-                             for l, e in zip(*np.nonzero(act))]
-                hit, missing = self.residency.access(units, self._step_idx)
-                sc = staged_counts or [[0] * n_l
-                                       for _ in range(
-                                           self.residency.n_shards)]
-                per_shard_miss = [list(r) for r in sc]
-                for lyr in range(n_l):
-                    df = self.residency.fetch(
-                        [u for u in missing if u[0] == lyr],
-                        self._step_idx)
-                    for s_i, c in enumerate(df["per_shard"]):
-                        per_shard_miss[s_i][lyr] += c
-                self.residency.note_step(units, self._step_idx)
-                n_hits, n_miss = len(hit), len(missing)
-                hit_by_layer = tuple(
-                    sum(1 for u in hit if u[0] == lyr)
-                    for lyr in range(n_l))
-                miss_by_layer = tuple(
-                    sum(1 for u in missing if u[0] == lyr)
-                    for lyr in range(n_l))
-                step_fetch_bytes = sum(
-                    sum(r) for r in per_shard_miss) * \
-                    self.residency.expert_bytes
-            else:
-                active_ids = []
-                if "experts_active" in aux:
-                    act = np.asarray(aux["experts_active"])      # [L, E]
-                    active_ids = np.nonzero(act.any(axis=0))[0]
-                hit, missing = self.residency.access(active_ids,
-                                                     self._step_idx)
-                df = self.residency.fetch(missing, self._step_idx)
-                pc = prefetch_counts or [0] * self.residency.n_shards
-                per_shard_miss = [p + d
-                                  for p, d in zip(pc, df["per_shard"])]
-                self.residency.note_step(active_ids, self._step_idx)
-                n_hits, n_miss = len(hit), len(missing)
-                step_fetch_bytes = sum(per_shard_miss) * \
-                    self.residency.expert_bytes
-            step_evictions = self.residency.evictions - ev0
-        tokens_per_row = [int(mask[i].sum()) for i in range(b)]
-        cost = cm.batch_iteration_time(
-            self.cfg, self.hw, tokens_per_row,
-            [int(n) for n in lengths_before],
-            unique_experts=union,
-            per_request_unique=(None if per_row is None else
-                                [per_row[i] if i in spans else 0.0
-                                 for i in range(b)]),
-            affinity=self.affinity, window=self.window,
-            prefill_tokens=[chunk_plan.get(i, 0) for i in range(b)],
-            placement=self.placement,
-            per_shard_unique=(None if shard_mean is None
-                              else list(shard_mean)),
-            residency=self.residency, per_shard_miss=per_shard_miss,
-            fetch_hide=fetch_hide, staged_per_shard=staged_counts,
-            precision=self.precision)
-        self._last_t_iter = float(cost["t_iter"])
-        t_verify_shared = (wall_verify if self.clock == "wall"
-                           else cost["t_iter"])
-
-        # EP steering signal: fold this pass's measured per-row shard
-        # profile into the EMA the next plan() steers with
-        if row_shard is not None:
-            for i in spans:
-                prof = row_shard[i]
-                tot = float(prof.sum())
-                if tot <= 0:
-                    continue
-                prof = prof / tot
-                old = self._shard_profiles.get(i)
-                self._shard_profiles[i] = (prof if old is None
-                                           else 0.5 * old + 0.5 * prof)
-        # online replica routing: fold this pass's measured per-shard
-        # activation into an EMA and re-point each replicated expert at its
-        # currently-coolest replica for the NEXT pass (the serving-side
-        # half of the min-over-replicas relief the oracle prices)
-        step_moves = 0
-        if self._replica_routes is not None and shard_mean is not None:
-            step_moves = self._update_replica_routes(np.asarray(shard_mean))
-
-        # 7. feed back per request; advance token state
-        emitted_by_slot = {}
-        step_iter_tel = {}   # this step's records, for the t_pass backfill
-        occupancy = len(spans)
-        n_tokens = sum(tokens_per_row)
-        padded = occupancy * t_max - n_tokens
-        t_overhead = 0.0
-        for i in decode_rows:
-            s = slots[i]
-            res = results[i]
-            k_eff = len(drafts[i])
-            emitted, stopped = _truncate_at_stop(
-                res.accepted + [res.next_token], s.stop_token)
-            s.out.extend(emitted)
-            s.history.extend(emitted)
-            s.last_tok = emitted[-1]
-
-            t_verify = self._attr_share(cost, i, wall_verify, occupancy)
-            t_draft = (wall_draft[i] if self.clock == "wall"
-                       else cm.draft_time(self.hw, k_eff,
-                                          s.drafter.active_params,
-                                          precision=self.drafter_precision))
-            t_sample = (wall_sample[i] if self.clock == "wall"
-                        else cm.sample_time(k_eff))
-            t_iter = t_draft + t_verify + t_sample
-            t_overhead = max(t_overhead, t_draft + t_sample)
-
-            s.controller.observe(len(emitted), t_iter, t_draft=t_draft,
-                                 t_verify=t_verify, t_sample=t_sample,
-                                 k=k_eff if k_req[i] > 0 else 0,
-                                 batch=occupancy)
-            step_iter_tel[i] = IterationTelemetry(
-                iteration=s.iteration, k_requested=k_req[i],
-                k_drafted=k_eff, tokens_emitted=len(emitted),
-                t_iter=t_iter, t_draft=t_draft, t_verify=t_verify,
-                t_sample=t_sample,
-                unique_experts=(float(per_row[i]) if per_row is not None
-                                else 0.0),
-                context_len=int(lengths_before[i]),
-                phase=getattr(s.controller, "phase", ""),
-                utility=s.controller.utility(),
-                batch_occupancy=occupancy,
-                union_experts=union or 0.0,
-                padding_frac=padded / (n_tokens + padded) if n_tokens else 0.0,
-                k_granted=plan.decisions[i].granted,
-                plan_held=plan.decisions[i].held,
-                slo_capped=plan.decisions[i].slo_capped)
-            s.tel.iterations.append(step_iter_tel[i])
-            s.iteration += 1
-            emitted_by_slot[i] = emitted
-            self._maybe_finish(s, stopped=stopped)
-
-        # 8. prefill bookkeeping: attribute this chunk's share of the pass
-        # to the request's TTFT clock; on the final chunk, sample the first
-        # output token and flip the slot to decode
-        finished_prefill = []
-        for i, n in chunk_plan.items():
-            s = slots[i]
-            s.tel.t_prefill += self._attr_share(cost, i, wall_verify,
-                                                occupancy)
-            s.tel.prefill_chunks += 1
-            s.prefill_pos += n
-            if s.prefill_pos >= len(s.prompt):
-                first = _sample_logits(s.rng, lo[i, n - 1],
-                                       self.temperature)
-                s.history.append(first)
-                s.out = [first]
-                s.last_tok = first
-                s.phase = "decode"
-                finished_prefill.append(i)
-                emitted_by_slot[i] = [first]
-                self._maybe_finish(s,
-                                   stopped=s.stop_token is not None
-                                   and first == s.stop_token)
-
-        step_tel = StepTelemetry(
-            step=self._step_idx, occupancy=occupancy,
-            tokens_in_flight=n_tokens, padded_tokens=padded,
-            union_experts=union or 0.0,
-            t_step=t_verify_shared, t_overhead=t_overhead,
-            joined=self._joined_since_step,
-            retired=sum(1 for i in spans if slots[i].done),
-            prefill_tokens=sum(chunk_plan.values()),
-            decode_tokens=sum(len(spans[i]) for i in decode_rows),
-            k_requested=plan.requested_total,
-            k_granted=plan.granted_total,
-            preempted=plan.preempted,
-            held_tests=plan.held,
-            t_step_predicted=plan.t_predicted,
-            t_base_predicted=plan.t_base,
-            tokens_predicted=plan.tokens_predicted,
-            planned=plan.priced,
-            slo_denied=plan.slo_denied,
-            shard_experts=tuple(cost.get("shard_unique", ())),
-            max_shard_experts=cost.get("max_shard_experts", 0.0),
-            hot_shard=cost.get("hot_shard", -1),
-            shard_imbalance=cost.get("imbalance", 1.0),
-            t_a2a=cost.get("t_a2a", 0.0),
-            replica_moves=step_moves,
-            packed_experts=(packed_expert_cap(self.cfg, b * t_max)
-                            if self.packed else 0),
-            prefetch_hits=n_hits,
-            prefetch_misses=n_miss,
-            evictions=step_evictions,
-            fetch_bytes=step_fetch_bytes,
-            t_fetch=cost.get("t_fetch_unhidden", 0.0),
-            fetch_hide=(min(float(fetch_hide[0]),
-                            max(r[0] for r in staged_counts)
-                            * self.residency.expert_bytes
+                    # the model-clock draft+sample window of this step — what
+                    # a prefetched byte can hide behind (same expressions as
+                    # stage 7's t_overhead, known here because K_i are fixed)
+                    base_hide = max(
+                        (cm.draft_time(self.hw, len(drafts[i]),
+                                       slots[i].drafter.active_params,
+                                       precision=self.drafter_precision)
+                         + cm.sample_time(len(drafts[i]))
+                         for i in decode_rows), default=0.0)
+                if self._layered:
+                    # layered streaming: layer l's staged fetches additionally
+                    # hide behind the compute of layers < l in THIS pass (the
+                    # planner's predicted base pass is the compute estimate —
+                    # priced for the current batch composition, so membership
+                    # churn reprices the window the same step it happens)...
+                    if self.prefetch and self.double_buffer:
+                        # ...and, double-buffered, behind the tail of the
+                        # PREVIOUS pass that ran after its last MoE layer
+                        # consumed weights — the link was idle there
+                        base_hide += (1.0 - self._hide_fracs[-1]) \
+                            * self._last_t_iter
+                    fetch_hide = cm.fetch_hide_schedule(self.cfg, base_hide,
+                                                        plan.t_base)
+                    n_l = self.residency.n_unit_layers
+                    staged_counts = [[0] * n_l
+                                     for _ in range(self.residency.n_shards)]
+                    if self._probe is not None:
+                        pred = np.asarray(self._probe(self.params,
+                                                      jnp.asarray(toks),
+                                                      jnp.asarray(mask)))
+                        if self._last_moe_h is not None:
+                            # deep layers nominate from the PREVIOUS pass's
+                            # per-layer hidden states — layer l's router over
+                            # layer l's actual inputs, not the embedding
+                            # (layer 0 keeps the current spans' embed probe:
+                            # its routing input IS close to the embedding)
+                            hp = np.asarray(self._hprobe(self.params,
+                                                         self._last_moe_h,
+                                                         self._last_mask))
+                            pred = np.concatenate([pred[:1], hp[1:]], axis=0)
+                        # nominate layer-by-layer in pipeline order —
+                        # most-confident first within a layer, exactly the
+                        # order the link drains and the cumulative staged
+                        # cap credits (fetch_time_layered)
+                        for lyr in range(n_l):
+                            row = pred[lyr]
+                            nominated = sorted(
+                                ((lyr, int(e)) for e in np.nonzero(row)[0]
+                                 if row[e] >= self.prefetch_min_count),
+                                key=lambda u: (-int(row[u[1]]), u[1]))
+                            pf = self.residency.fetch(nominated,
+                                                      self._step_idx,
+                                                      stage=True)
+                            for s_i, c in enumerate(pf["per_shard"]):
+                                staged_counts[s_i][lyr] = c
+                else:
+                    fetch_hide = base_hide
+                    if self.prefetch:
+                        # ... plus the dense compute ahead of the first MoE
+                        # layer: the DMA issued now keeps streaming while
+                        # embed + leading layers run, and the weights are
+                        # only needed when that layer routes (the planner's
+                        # predicted base pass for THIS batch composition is
+                        # the compute estimate — the previous pass's t_iter
+                        # overstates the window right after rows retire)
+                        fetch_hide += self._pre_moe_frac * plan.t_base
+                    if self._probe is not None:
+                        pred = np.asarray(self._probe(self.params,
+                                                      jnp.asarray(toks),
+                                                      jnp.asarray(mask))
+                                          ).sum(axis=0)        # [L,E] -> [E]
+                        # most-confident first: experts routed by more
+                        # predicted (token, layer) slots stage before marginal
+                        # ones (the ordering the min-count filter and hide
+                        # window reward)
+                        nominated = sorted(
+                            (int(e) for e in np.nonzero(pred)[0]
+                             if pred[e] >= self.prefetch_min_count),
+                            key=lambda e: (-int(pred[e]), e))
+                        pf = self.residency.fetch(nominated, self._step_idx,
+                                                  stage=True)
+                        prefetch_counts = pf["per_shard"]
+                        # honest hide: the draft+sample window only hides
+                        # bytes that were actually prefetched during it —
+                        # demand misses are discovered at pass time and can
+                        # never hide, so cap the credit at the prefetched
+                        # fetch time (the layered path applies the same cap
+                        # per layer inside fetch_time_layered, from
+                        # staged_counts)
+                        fetch_hide = min(
+                            fetch_hide,
+                            max(prefetch_counts) * self.residency.expert_bytes
                             / self.hw.host_bw)
-                        if isinstance(fetch_hide, list)
-                        else float(fetch_hide)),
-            t_fetch_by_layer=tuple(cost.get("t_fetch_by_layer", ())),
-            prefetch_hits_by_layer=hit_by_layer,
-            prefetch_misses_by_layer=miss_by_layer,
-            precision=cost.get("precision", ""),
-            expert_bytes_saved=cost.get("expert_bytes_saved", 0.0))
-        self.telemetry.steps.append(step_tel)
-        # every decode row experienced the WHOLE pass between its tokens —
-        # the latency quantity SLOs bound (vs t_iter's attributed share)
-        for it_tel in step_iter_tel.values():
-            it_tel.t_pass = step_tel.t_total
-        self.now += step_tel.t_total
-        for i in finished_prefill:  # first token exists as of end-of-step
-            s = slots[i]
-            s.tel.ttft = max(self.now - s.t_submit, 0.0)
-        self._joined_since_step = 0
-        self._step_idx += 1
-        return emitted_by_slot
+
+        with TraceAnnotation("engine.dispatch"):
+            # 3. shared verification pass
+            t1 = time.perf_counter()
+            if self._replica_routes is not None:
+                lo, new_cache, aux, staged = self._decode(
+                    self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(mask), jnp.asarray(self._replica_routes))
+            else:
+                lo, new_cache, aux, staged = self._decode(
+                    self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(mask))
+        with TraceAnnotation("engine.fetch_logits"):
+            lo = np.asarray(lo, np.float32)            # [B, T_max, V]
+            wall_verify = time.perf_counter() - t1
+            if not np.asarray(aux["logits_finite"])[mask].all():
+                raise FloatingPointError(
+                    f"non-finite logits in the pass of step {self._step_idx}")
+            if self._hprobe is not None and "moe_h" in aux:
+                # keep this pass's per-layer MoE inputs (+ their mask) as the
+                # NEXT step's deep-layer nomination basis
+                self._last_moe_h = aux["moe_h"]        # [L, B, T, d] (device)
+                self._last_mask = jnp.asarray(mask)
+
+        with TraceAnnotation("engine.verify"):
+            # 4. per-row rejection sampling (decode rows only — prefill
+            # chunks commit all their real tokens, nothing to verify)
+            results, wall_sample = {}, {}
+            for i in decode_rows:
+                s = slots[i]
+                n_i = 1 + len(drafts[i])
+                t2 = time.perf_counter()
+                if self.temperature <= 0:
+                    results[i] = greedy_verify(lo[i, :n_i], drafts[i])
+                else:
+                    probs = np.asarray(logits_to_probs(
+                        jnp.asarray(lo[i, :n_i]), self.temperature))
+                    results[i] = rejection_sample(s.rng, probs, drafts[i],
+                                                  draft_probs[i])
+                wall_sample[i] = time.perf_counter() - t2
+
+        with TraceAnnotation("engine.rollback"):
+            # 5. vectorized per-row rollback (idle rows keep length unchanged;
+            # prefill rows keep their whole real chunk, dropping the padding)
+            n_keep = np.zeros((b,), np.int32)
+            for i in decode_rows:
+                n_keep[i] = 1 + results[i].n_accepted
+            for i, n in chunk_plan.items():
+                n_keep[i] = n
+            self.cache = T.rollback_cache(self.cfg, new_cache, staged,
+                                          jnp.asarray(n_keep),
+                                          jnp.asarray(lengths_before))
+
+        with TraceAnnotation("engine.cost"):
+            # 6. batch-aware cost accounting + marginal attribution
+            union = per_row = shard_mean = row_shard = None
+            if self.cfg.is_moe and "unique_experts" in aux:
+                # mean over *layers* of the masked per-layer union [L]. (The EP
+                # apply path used to land its per-source-shard counts on this
+                # key, and a bare np.mean folded them into a scalar that was
+                # neither the union nor the gating shard; the union is now
+                # recomputed from the gathered expert ids upstream, and the
+                # per-shard view arrives separately below.)
+                union = float(np.mean(np.asarray(aux["unique_experts"])))
+            if self.cfg.is_moe and "unique_experts_row" in aux:
+                per_row = np.mean(np.asarray(aux["unique_experts_row"],
+                                             np.float64), axis=0)   # [B]
+            if self._ep and "unique_experts_shard" in aux:
+                shard_mean = np.mean(np.asarray(aux["unique_experts_shard"],
+                                                np.float64), axis=0)   # [S]
+                row_shard = np.mean(np.asarray(aux["unique_experts_row_shard"],
+                                               np.float64), axis=0)    # [B,S]
+            # residency bookkeeping: classify the pass's ACTUAL activated
+            # host-tier experts into prefetch hits and demand misses, fetch
+            # the misses (discovered too late to hide), evict-and-admit, and
+            # price the pass with the measured per-shard fetch counts
+            per_shard_miss = None
+            n_hits = n_miss = step_evictions = 0
+            step_fetch_bytes = 0.0
+            hit_by_layer = miss_by_layer = ()
+            if self._offload:
+                ev0 = self.residency.evictions
+                if self._layered:
+                    # per-(layer, expert) units: each MoE layer's activated
+                    # slices classify and demand-fetch independently, in
+                    # pipeline (layer) order — the measured [S][L] counts the
+                    # layered pricing consumes
+                    n_l = self.residency.n_unit_layers
+                    units = []
+                    if "experts_active" in aux:
+                        act = np.asarray(aux["experts_active"])  # [L, E]
+                        units = [(int(l), int(e))
+                                 for l, e in zip(*np.nonzero(act))]
+                    hit, missing = self.residency.access(units, self._step_idx)
+                    sc = staged_counts or [[0] * n_l
+                                           for _ in range(
+                                               self.residency.n_shards)]
+                    per_shard_miss = [list(r) for r in sc]
+                    for lyr in range(n_l):
+                        df = self.residency.fetch(
+                            [u for u in missing if u[0] == lyr],
+                            self._step_idx)
+                        for s_i, c in enumerate(df["per_shard"]):
+                            per_shard_miss[s_i][lyr] += c
+                    self.residency.note_step(units, self._step_idx)
+                    n_hits, n_miss = len(hit), len(missing)
+                    hit_by_layer = tuple(
+                        sum(1 for u in hit if u[0] == lyr)
+                        for lyr in range(n_l))
+                    miss_by_layer = tuple(
+                        sum(1 for u in missing if u[0] == lyr)
+                        for lyr in range(n_l))
+                    step_fetch_bytes = sum(
+                        sum(r) for r in per_shard_miss) * \
+                        self.residency.expert_bytes
+                else:
+                    active_ids = []
+                    if "experts_active" in aux:
+                        act = np.asarray(aux["experts_active"])      # [L, E]
+                        active_ids = np.nonzero(act.any(axis=0))[0]
+                    hit, missing = self.residency.access(active_ids,
+                                                         self._step_idx)
+                    df = self.residency.fetch(missing, self._step_idx)
+                    pc = prefetch_counts or [0] * self.residency.n_shards
+                    per_shard_miss = [p + d
+                                      for p, d in zip(pc, df["per_shard"])]
+                    self.residency.note_step(active_ids, self._step_idx)
+                    n_hits, n_miss = len(hit), len(missing)
+                    step_fetch_bytes = sum(per_shard_miss) * \
+                        self.residency.expert_bytes
+                step_evictions = self.residency.evictions - ev0
+            tokens_per_row = [int(mask[i].sum()) for i in range(b)]
+            cost = cm.batch_iteration_time(
+                self.cfg, self.hw, tokens_per_row,
+                [int(n) for n in lengths_before],
+                unique_experts=union,
+                per_request_unique=(None if per_row is None else
+                                    [per_row[i] if i in spans else 0.0
+                                     for i in range(b)]),
+                affinity=self.affinity, window=self.window,
+                prefill_tokens=[chunk_plan.get(i, 0) for i in range(b)],
+                placement=self.placement,
+                per_shard_unique=(None if shard_mean is None
+                                  else list(shard_mean)),
+                residency=self.residency, per_shard_miss=per_shard_miss,
+                fetch_hide=fetch_hide, staged_per_shard=staged_counts,
+                precision=self.precision)
+            self._last_t_iter = float(cost["t_iter"])
+            t_verify_shared = (wall_verify if self.clock == "wall"
+                               else cost["t_iter"])
+
+            # EP steering signal: fold this pass's measured per-row shard
+            # profile into the EMA the next plan() steers with
+            if row_shard is not None:
+                for i in spans:
+                    prof = row_shard[i]
+                    tot = float(prof.sum())
+                    if tot <= 0:
+                        continue
+                    prof = prof / tot
+                    old = self._shard_profiles.get(i)
+                    self._shard_profiles[i] = (prof if old is None
+                                               else 0.5 * old + 0.5 * prof)
+            # online replica routing: fold this pass's measured per-shard
+            # activation into an EMA and re-point each replicated expert at its
+            # currently-coolest replica for the NEXT pass (the serving-side
+            # half of the min-over-replicas relief the oracle prices)
+            step_moves = 0
+            if self._replica_routes is not None and shard_mean is not None:
+                step_moves = self._update_replica_routes(
+                    np.asarray(shard_mean))
+
+        with TraceAnnotation("engine.feedback"):
+            # 7. feed back per request; advance token state
+            emitted_by_slot = {}
+            step_iter_tel = {}   # this step's records, for the t_pass backfill
+            occupancy = len(spans)
+            n_tokens = sum(tokens_per_row)
+            padded = occupancy * t_max - n_tokens
+            t_overhead = 0.0
+            for i in decode_rows:
+                s = slots[i]
+                res = results[i]
+                k_eff = len(drafts[i])
+                emitted, stopped = _truncate_at_stop(
+                    res.accepted + [res.next_token], s.stop_token)
+                s.out.extend(emitted)
+                s.history.extend(emitted)
+                s.last_tok = emitted[-1]
+
+                t_verify = self._attr_share(cost, i, wall_verify, occupancy)
+                t_draft = (wall_draft[i] if self.clock == "wall"
+                           else cm.draft_time(
+                               self.hw, k_eff, s.drafter.active_params,
+                               precision=self.drafter_precision))
+                t_sample = (wall_sample[i] if self.clock == "wall"
+                            else cm.sample_time(k_eff))
+                t_iter = t_draft + t_verify + t_sample
+                t_overhead = max(t_overhead, t_draft + t_sample)
+
+                s.controller.observe(len(emitted), t_iter, t_draft=t_draft,
+                                     t_verify=t_verify, t_sample=t_sample,
+                                     k=k_eff if k_req[i] > 0 else 0,
+                                     batch=occupancy)
+                step_iter_tel[i] = IterationTelemetry(
+                    iteration=s.iteration, k_requested=k_req[i],
+                    k_drafted=k_eff, tokens_emitted=len(emitted),
+                    t_iter=t_iter, t_draft=t_draft, t_verify=t_verify,
+                    t_sample=t_sample,
+                    unique_experts=(float(per_row[i]) if per_row is not None
+                                    else 0.0),
+                    context_len=int(lengths_before[i]),
+                    phase=getattr(s.controller, "phase", ""),
+                    utility=s.controller.utility(),
+                    batch_occupancy=occupancy,
+                    union_experts=union or 0.0,
+                    padding_frac=(padded / (n_tokens + padded) if n_tokens
+                                  else 0.0),
+                    k_granted=plan.decisions[i].granted,
+                    plan_held=plan.decisions[i].held,
+                    slo_capped=plan.decisions[i].slo_capped)
+                s.tel.iterations.append(step_iter_tel[i])
+                s.iteration += 1
+                emitted_by_slot[i] = emitted
+                self._maybe_finish(s, stopped=stopped)
+
+            # 8. prefill bookkeeping: attribute this chunk's share of the pass
+            # to the request's TTFT clock; on the final chunk, sample the first
+            # output token and flip the slot to decode
+            finished_prefill = []
+            for i, n in chunk_plan.items():
+                s = slots[i]
+                s.tel.t_prefill += self._attr_share(cost, i, wall_verify,
+                                                    occupancy)
+                s.tel.prefill_chunks += 1
+                s.prefill_pos += n
+                if s.prefill_pos >= len(s.prompt):
+                    first = _sample_logits(s.rng, lo[i, n - 1],
+                                           self.temperature)
+                    s.history.append(first)
+                    s.out = [first]
+                    s.last_tok = first
+                    s.phase = "decode"
+                    finished_prefill.append(i)
+                    emitted_by_slot[i] = [first]
+                    self._maybe_finish(s,
+                                       stopped=s.stop_token is not None
+                                       and first == s.stop_token)
+
+            step_tel = StepTelemetry(
+                step=self._step_idx, occupancy=occupancy,
+                tokens_in_flight=n_tokens, padded_tokens=padded,
+                union_experts=union or 0.0,
+                t_step=t_verify_shared, t_overhead=t_overhead,
+                joined=self._joined_since_step,
+                retired=sum(1 for i in spans if slots[i].done),
+                prefill_tokens=sum(chunk_plan.values()),
+                decode_tokens=sum(len(spans[i]) for i in decode_rows),
+                k_requested=plan.requested_total,
+                k_granted=plan.granted_total,
+                preempted=plan.preempted,
+                held_tests=plan.held,
+                t_step_predicted=plan.t_predicted,
+                t_base_predicted=plan.t_base,
+                tokens_predicted=plan.tokens_predicted,
+                planned=plan.priced,
+                slo_denied=plan.slo_denied,
+                shard_experts=tuple(cost.get("shard_unique", ())),
+                max_shard_experts=cost.get("max_shard_experts", 0.0),
+                hot_shard=cost.get("hot_shard", -1),
+                shard_imbalance=cost.get("imbalance", 1.0),
+                t_a2a=cost.get("t_a2a", 0.0),
+                replica_moves=step_moves,
+                packed_experts=(packed_expert_cap(self.cfg, b * t_max)
+                                if self.packed else 0),
+                prefetch_hits=n_hits,
+                prefetch_misses=n_miss,
+                evictions=step_evictions,
+                fetch_bytes=step_fetch_bytes,
+                t_fetch=cost.get("t_fetch_unhidden", 0.0),
+                fetch_hide=(min(float(fetch_hide[0]),
+                                max(r[0] for r in staged_counts)
+                                * self.residency.expert_bytes
+                                / self.hw.host_bw)
+                            if isinstance(fetch_hide, list)
+                            else float(fetch_hide)),
+                t_fetch_by_layer=tuple(cost.get("t_fetch_by_layer", ())),
+                prefetch_hits_by_layer=hit_by_layer,
+                prefetch_misses_by_layer=miss_by_layer,
+                precision=cost.get("precision", ""),
+                expert_bytes_saved=cost.get("expert_bytes_saved", 0.0))
+            self.telemetry.steps.append(step_tel)
+            # every decode row experienced the WHOLE pass between its
+            # tokens — the latency quantity SLOs bound (vs t_iter's
+            # attributed share)
+            for it_tel in step_iter_tel.values():
+                it_tel.t_pass = step_tel.t_total
+            self.now += step_tel.t_total
+            for i in finished_prefill:  # first token exists as of end-of-step
+                s = slots[i]
+                s.tel.ttft = max(self.now - s.t_submit, 0.0)
+            self._joined_since_step = 0
+            self._step_idx += 1
+            return emitted_by_slot
 
     # -- batch=1 compatibility ------------------------------------------ #
 

@@ -20,6 +20,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, Iterable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.planner import DEFER, SHED, AdmissionConstraint
 from repro.core.slo import LATENCY, RequestSLO
 
@@ -223,15 +225,18 @@ class ContinuousBatchingScheduler:
 
     def step(self) -> bool:
         """Admit, run one engine step, retire. False when fully drained."""
-        self._admit()
-        if not self.engine.active_slots and not self.queue:
-            return False
-        if not self.engine.active_slots:
-            # the whole queue was shed this round — drained, no pass to run
-            return bool(self.queue)
-        self.engine.step()
-        self._retire_finished()
-        return bool(self.queue or self.engine.active_slots)
+        with TraceAnnotation("sched.step"):
+            with TraceAnnotation("sched.admit"):
+                self._admit()
+            if not self.engine.active_slots and not self.queue:
+                return False
+            if not self.engine.active_slots:
+                # the whole queue was shed this round — drained, no pass
+                return bool(self.queue)
+            self.engine.step()
+            with TraceAnnotation("sched.retire"):
+                self._retire_finished()
+            return bool(self.queue or self.engine.active_slots)
 
     def run(self, requests: Iterable[Request]) -> List[GenerationResult]:
         """Serve `requests` to completion; results in submission order."""
