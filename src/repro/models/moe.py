@@ -159,6 +159,17 @@ def packed_expert_cap(cfg, n_tokens: int) -> int:
     return min(bucket_length(u), cfg.num_experts)
 
 
+def experts_in_place(cfg, p, n_tokens: int) -> bool:
+    """Whether the packed path of an `n_tokens`-token pass contracts the
+    stacked expert weights of `p` (one layer's or the stacked layers' MoE
+    params) in place instead of gathering its union slots: true once the
+    bucketed cap U_pad reaches E, where the gather would only permute all
+    E experts and copy every stack, unless the experts are stored int8
+    (that storage keeps its 1-byte/param gather)."""
+    return ("w_up_q8" not in p
+            and packed_expert_cap(cfg, n_tokens) == cfg.num_experts)
+
+
 def moe_pass_counters(cfg, n_tokens: int, *, capacity_policy: str = "exact",
                       packed: bool = False, weight_bytes: int = None,
                       precision=None) -> dict:
@@ -166,7 +177,8 @@ def moe_pass_counters(cfg, n_tokens: int, *, capacity_policy: str = "exact",
     bytes the dispatch path streams and the FLOPs its stacked matmuls
     execute.  These mirror the implementation exactly — the dense path
     einsums over all E experts; the packed path gathers and multiplies
-    only the U_pad = `packed_expert_cap` slots — and back the scaling
+    only the U_pad = `packed_expert_cap` slots (reading all E in place
+    once U_pad == E) — and back the scaling
     gates in `benchmarks/serving_micro.py --calibrate`.  Bytes price at
     the precision spec's expert class (`core.cost_model.Precision`;
     `weight_bytes` kept as a legacy uniform override) — quantized expert
@@ -243,17 +255,21 @@ def apply_moe(cfg, p, x2d, *, capacity_policy: str = "train",
               packed: bool = False, kernel_backend: str | None = None):
     """x2d: [T,d] -> (y [T,d], aux dict with routing telemetry).
 
-    packed=True takes the union-packed verification path: the activated
-    experts are compacted into the leading `packed_expert_cap(cfg, T)`
-    slots, so weight gathers, the dispatch buffer and the FFN matmuls all
-    scale with the (bucketed) union U rather than E.  With
+    packed=True takes the union-packed verification path: below
+    saturation the activated experts are compacted into the leading
+    `packed_expert_cap(cfg, T)` slots, so weight gathers, the dispatch
+    buffer and the FFN matmuls all scale with the (bucketed) union U
+    rather than E.  At saturation (`experts_in_place`: U_pad == E) the
+    pass takes the dense dispatch by expert id and reads all E stacked
+    experts in place, since a gather of all E only copies them.  With
     kernel_backend=None the packed FFN runs the same inline einsums as the
     dense path — identical contraction structure and dtype promotion, so
     the outputs are bit-identical and rejection sampling sees no numerics
-    drift.  kernel_backend="pallas"/"interpret"/"ref" routes the packed
-    FFN through `kernels.moe_gmm.moe_gmm_fused` instead (allclose, not
-    bitwise).  The packed path is the single-host serving hot path; the
-    GSPMD dispatch-shard constraints and the ep-a2a path stay dense.
+    drift.  kernel_backend="pallas"/"interpret"/"ref" keeps the union
+    gather at any U_pad and routes the packed FFN through
+    `kernels.moe_gmm.moe_gmm_fused` instead (allclose, not bitwise).
+    The packed path is the single-host serving hot path; the GSPMD
+    dispatch-shard constraints and the ep-a2a path stay dense.
 
     Quantized expert storage (docs/quantization.md): when `p` holds
     int8-packed experts (`w_up_q8` + `w_up_s` per-expert scales, from
@@ -311,7 +327,8 @@ def apply_moe(cfg, p, x2d, *, capacity_policy: str = "train",
     flat_p = jnp.where(keep, flat_p, c)  # overflow rows scatter to a spill slot
 
     x_rep = jnp.repeat(x2d, k, axis=0)                        # [T*k,d]
-    if packed:
+    if packed and (kernel_backend is not None
+                   or not experts_in_place(cfg, p, t)):
         # --- union compaction: map the activated experts onto the leading
         # U_pad packed slots (active experts first, ascending id — a
         # deterministic, trace-stable permutation).  Every routed expert
@@ -401,7 +418,9 @@ def apply_moe(cfg, p, x2d, *, capacity_policy: str = "train",
         out = jnp.concatenate([out, pad], axis=1)
         y_rep = out[flat_u, jnp.where(keep, flat_p, c)]       # [T*k,d]
     else:
-        # --- dispatch: scatter tokens into [E, C(+spill), d]
+        # --- dispatch: scatter tokens into [E, C(+spill), d]; the packed
+        # path at saturation lands here too, so the einsums below read the
+        # stacked weights in place rather than a gathered copy of all E
         disp = jnp.zeros((e, c + 1, d), x2d.dtype)
         disp = disp.at[flat_e, flat_p].set(x_rep)
         disp = disp[:, :c]                                    # drop spill slot

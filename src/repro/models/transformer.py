@@ -718,8 +718,9 @@ def decode_step(cfg, params, cache, tokens, *, embeds=None, rope_pos=None,
     passes one); in the traced case `ep_n_shards` must carry the static
     shard count.  `moe_packed=True` runs MoE layers on the union-packed
     verification path (see models/moe.apply_moe) — bit-identical outputs,
-    union-scaled weight traffic.  `want_moe_h=True` additionally returns
-    the per-layer MoE inputs (`aux["moe_h"]` [L,B,T,D], the post-ln2
+    union-scaled weight traffic below saturation; at saturation (U_pad ==
+    E) it reads all E experts in place.  `want_moe_h=True` additionally
+    returns the per-layer MoE inputs (`aux["moe_h"]` [L,B,T,D], the post-ln2
     hidden states feeding each layer's router) — the layered prefetcher's
     per-layer probe basis (docs/offload.md).
     Returns (logits [B,T,V], new_cache, aux, staged)."""

@@ -45,7 +45,7 @@ from repro.core.controller import CascadeController, StaticKController
 from repro.core.planner import BatchSpecPlanner, PlannerConfig
 from repro.core.slo import RequestSLO
 from repro.models import transformer as T
-from repro.models.moe import packed_expert_cap
+from repro.models.moe import experts_in_place, packed_expert_cap
 
 from .drafter import Drafter, NGramDrafter
 from .sampler import greedy_verify, logits_to_probs, rejection_sample, sample_token
@@ -611,6 +611,7 @@ class BatchedEngine:
                                          enc_out=e))
         #: union-packed verification path (models/moe.apply_moe(packed=
         #: True)): bit-identical outputs, union-scaled weight traffic
+        #: below saturation, all E experts read in place at it
         self.packed = bool(packed)
         #: online replica routing: with replicated experts the engine
         #: re-routes each replicated expert to its currently-cheapest
@@ -1403,6 +1404,10 @@ class BatchedEngine:
                 replica_moves=step_moves,
                 packed_experts=(packed_expert_cap(self.cfg, b * t_max)
                                 if self.packed else 0),
+                experts_in_place=(self.packed and self.cfg.is_moe
+                                  and experts_in_place(
+                                      self.cfg, self.params["blocks"]["moe"],
+                                      b * t_max)),
                 prefetch_hits=n_hits,
                 prefetch_misses=n_miss,
                 evictions=step_evictions,

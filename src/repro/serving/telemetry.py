@@ -91,6 +91,8 @@ class StepTelemetry:
                                # replica after this pass (0 = no replicas)
     packed_experts: int = 0    # U_pad of the union-packed verification
                                # path (0 = dense path)
+    experts_in_place: bool = False  # the packed pass read the stacked
+                               # expert weights in place (U_pad == E)
     # -- residency/offload fields (defaults = all-hbm placement) ---------- #
     prefetch_hits: int = 0     # activated host-tier experts found resident
     prefetch_misses: int = 0   # activated host-tier experts demand-fetched

@@ -306,3 +306,27 @@ def test_packed_engine_streams_bit_identical_to_dense(tiny_moe, b):
     assert all(c <= cfg.num_experts for c in caps)
     dense_caps = [s.packed_experts for s in streams(False)[1].telemetry.steps]
     assert all(c == 0 for c in dense_caps)
+
+
+def test_step_telemetry_reports_experts_in_place(tiny_moe):
+    """A packed pass whose union cap reaches E reads the stacked experts in
+    place and says so: every pass of a B=8 engine (8 rows route 16 >= E
+    choices), and no one-token pass of a B=1 engine (U_pad = k < E)."""
+    cfg, params = tiny_moe
+
+    def steps(b, k):
+        eng = BatchedEngine(cfg, params, lambda: NGramDrafter(),
+                            max_batch=b, max_len=64, temperature=0.0,
+                            clock="model", seed=0, packed=True)
+        ContinuousBatchingScheduler(
+            eng, controller_factory=lambda: StaticKController(k)).run(
+            [Request(request_id=f"r{i}", prompt=[3 + i, 5, 7] * 3,
+                     max_new=4) for i in range(b)])
+        assert eng.telemetry.steps
+        for s in eng.telemetry.steps:
+            assert s.experts_in_place == (s.packed_experts
+                                          == cfg.num_experts)
+        return [s.experts_in_place for s in eng.telemetry.steps]
+
+    assert all(steps(8, 2))
+    assert not any(steps(1, 0))

@@ -11,6 +11,7 @@ imports every test file."""
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,23 +88,43 @@ def test_fused_moe_kernel_compiles(one_chip, width, quant):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_served_decode_step_fits_one_chip(one_chip):
+def _defined_with_shape(hlo_text, shapes):
+    """Instructions of the optimized HLO whose result has one of `shapes`
+    (bf16), parameters and bitcasts left out: they define no new array."""
+    dims = "|".join(",".join(map(str, s)) for s in shapes)
+    pat = re.compile(rf"^\s*(?:ROOT )?%\S+ = bf16\[({dims})\]\S* "
+                     rf"(?!parameter\(|bitcast\()", re.M)
+    return pat.findall(hlo_text)
+
+
+@pytest.mark.parametrize("t", [1, 4, 32])
+def test_served_decode_step_fits_one_chip(one_chip, t):
     """The pass the serving path runs at published OLMoE widths, depth cut
-    to 8 layers: batch 8, 4-token spans, 2048-token per-row cache,
-    union-packed MoE."""
+    to 8 layers: batch 8, t-token spans, 2048-token per-row cache,
+    union-packed MoE. 8 rows route 64·t >= E (token, choice) pairs, so
+    the packed path reads each layer's expert stacks in place: the
+    program defines one array per expert matrix of a [64, d, F] stack
+    shape (the per-layer slice fused into its einsum), not a gathered
+    copy of each, and its scratch stays small."""
     cfg = dataclasses.replace(OLMOE, num_layers=8)
+    assert moe_mod.packed_expert_cap(cfg, 8 * t) == cfg.num_experts
     params = _on(one_chip, jax.eval_shape(
         functools.partial(T.init_params, cfg), jax.random.PRNGKey(0)))
     cache = _on(one_chip, jax.eval_shape(
         lambda: T.init_cache(cfg, 8, 2048, per_row=True)))
-    toks = jax.ShapeDtypeStruct((8, 4), jnp.int32, sharding=one_chip)
-    mask = jax.ShapeDtypeStruct((8, 4), jnp.bool_, sharding=one_chip)
-    step = jax.jit(lambda p, c, t, m: T.decode_step(
-        cfg, p, c, t, token_mask=m, moe_packed=True))
-    mem = step.lower(params, cache, toks, mask).compile().memory_analysis()
+    toks = jax.ShapeDtypeStruct((8, t), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((8, t), jnp.bool_, sharding=one_chip)
+    step = jax.jit(lambda p, c, tk, m: T.decode_step(
+        cfg, p, c, tk, token_mask=m, moe_packed=True))
+    compiled = step.lower(params, cache, toks, mask).compile()
+    mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BUDGET, total
+    e, d, f = cfg.num_experts, cfg.d_model, cfg.moe_d_ff
+    stacks = _defined_with_shape(compiled.as_text(), [(e, d, f), (e, f, d)])
+    assert len(stacks) <= 3, stacks
+    assert mem.temp_size_in_bytes < 0.3e9, mem.temp_size_in_bytes
 
 
 def test_expert_parallel_moe_compiles_on_four_chips(topo):

@@ -141,22 +141,50 @@ def test_vlm_mrope_positions(key):
 # Union-packed MoE dispatch (docs/kernels.md)
 # ===================================================================== #
 
-def test_packed_apply_moe_bit_identical(tiny_moe):
+def _gather_operand_shapes(jaxpr):
+    """Operand shapes of every gather in `jaxpr` and its sub-jaxprs."""
+    shapes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            shapes.append(tuple(eqn.invars[0].aval.shape))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    shapes += _gather_operand_shapes(sub)
+    return shapes
+
+
+@pytest.mark.parametrize("t", [1, 2, 3, 8, 33])
+def test_packed_apply_moe_bit_identical(tiny_moe, t):
     """The packed path's inlined einsums use the dense path's exact
     contraction structure and dtypes, so its output is bitwise equal —
-    across token counts spanning U=1-shaped unions to full saturation."""
+    across token counts spanning U=1-shaped unions to full saturation.
+    Below saturation it gathers the U_pad union slots of each expert
+    stack; at saturation (U_pad == E) it reads the stacks in place, with
+    no gather of an [E, d, F] stack."""
     from repro.models import moe
     cfg, _ = tiny_moe
     p = moe.init_moe(cfg, jax.random.PRNGKey(1), jnp.float32)
-    for t in (1, 2, 3, 8, 33):
-        x = jax.random.normal(jax.random.PRNGKey(t), (t, cfg.d_model),
-                              jnp.float32)
-        yd, auxd = moe.apply_moe(cfg, p, x, capacity_policy="exact")
-        yp, auxp = moe.apply_moe(cfg, p, x, capacity_policy="exact",
-                                 packed=True)
-        assert bool(jnp.all(yd == yp)), f"packed diverged at T={t}"
-        np.testing.assert_array_equal(np.asarray(auxd["unique_experts"]),
-                                      np.asarray(auxp["unique_experts"]))
+    x = jax.random.normal(jax.random.PRNGKey(t), (t, cfg.d_model),
+                          jnp.float32)
+    yd, auxd = moe.apply_moe(cfg, p, x, capacity_policy="exact")
+    yp, auxp = moe.apply_moe(cfg, p, x, capacity_policy="exact",
+                             packed=True)
+    assert bool(jnp.all(yd == yp)), f"packed diverged at T={t}"
+    np.testing.assert_array_equal(np.asarray(auxd["unique_experts"]),
+                                  np.asarray(auxp["unique_experts"]))
+    gathered = _gather_operand_shapes(jax.make_jaxpr(
+        lambda p, x: moe.apply_moe(cfg, p, x, capacity_policy="exact",
+                                   packed=True))(p, x).jaxpr)
+    stacks = {p[n].shape for n in ("w_gate", "w_up", "w_down") if n in p}
+    in_place = moe.experts_in_place(cfg, p, t)
+    assert in_place == (moe.packed_expert_cap(cfg, t) == cfg.num_experts)
+    assert in_place == (t >= cfg.num_experts // cfg.experts_per_token)
+    if in_place:
+        assert not stacks & set(gathered), gathered
+    else:
+        assert stacks <= set(gathered), gathered
 
 
 def test_packed_apply_moe_fused_kernel_close(tiny_moe):
