@@ -16,14 +16,13 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from . import archs
 from . import spec as specs
 from .check import check_served
 from .loop import ClosedLoop, supported_percentile, warm_up
-from .model import arch_of, program_config
 from .record import RunRecord
 from .traffic import ClosedLoopTraffic, prompt_tokens
 from .tracereduce import load as load_trace, reduce as reduce_trace
-from .weights import program_params
 
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 #: seconds after the window in which every request submitted in it must
@@ -67,12 +66,13 @@ def run_cell(cell: specs.Cell, *, seed: int, seconds: float, trace: bool,
 
     conf, traffic_spec = cell.config, cell.traffic
     serving = conf["serving"]
-    arch = arch_of(conf)
-    cfg = program_config(conf)
+    model = archs.for_config(conf)
+    arch = model.arch_of(conf)
+    cfg = model.program_config(conf)
     counter = CompileCounter()
     dev = jax.devices()[0]
 
-    params = program_params(arch, seed)
+    params = model.program_params(arch, seed)
     engine = BatchedEngine(
         cfg, params, NGramDrafter, max_batch=int(serving["max_batch"]),
         controller_factory=CascadeController, clock="wall", hw=hw,

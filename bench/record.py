@@ -3,15 +3,14 @@
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional
+from typing import Any, List, Optional
 
-from .model import Arch
 from .tracereduce import Reduction
 
 
 @dataclass
 class RunRecord:
-    arch: Arch
+    arch: Any                    # the configuration's architecture's Arch
     peaks: dict                  # the chip's entry of bench/peaks.json
     setup_s: float               # process start to window start
     window_s: float              # the window, host clock

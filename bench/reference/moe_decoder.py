@@ -17,7 +17,8 @@ and divides them by their sum where `norm_topk_prob` holds.
 Every product runs in float32 at the highest matmul precision, over the
 whole sequence at once, with no cache and no batching of requests into
 spans. It imports nothing of the program: the weights are the
-benchmark's own (`bench.weights`), regenerated one layer at a time.
+benchmark's own (`bench.archs.moe_decoder`), regenerated one layer at a
+time.
 
 The correctness control is this reference computed in the precision
 below the configuration's (`LOWER`): every product with a weight matrix
@@ -37,8 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..model import Arch
-from ..weights import global_weights, layer_weights
+from ..archs.moe_decoder import Arch, global_weights, layer_weights
 
 HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32

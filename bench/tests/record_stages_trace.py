@@ -88,11 +88,9 @@ def main(argv=None) -> int:
     import numpy as np
     from jax.profiler import TraceAnnotation
 
-    from bench import spec as specs
+    from bench import archs, spec as specs
     from bench.loop import ClosedLoop, warm_up
-    from bench.model import arch_of, program_config
     from bench.traffic import ClosedLoopTraffic, prompt_tokens
-    from bench.weights import program_params
     from repro.core import CascadeController, hardware_for_device_kind
     from repro.serving import (BatchedEngine, ContinuousBatchingScheduler,
                                NGramDrafter, Request)
@@ -104,9 +102,11 @@ def main(argv=None) -> int:
     conf = specs.load_json(DATA / "tiny-moe.json")
     mix = specs.load_json(DATA / "tiny-mix.json")
     serving = conf["serving"]
-    arch = arch_of(conf)
+    model = archs.for_config(conf)
+    arch = model.arch_of(conf)
     engine = BatchedEngine(
-        program_config(conf), program_params(arch, SEED), NGramDrafter,
+        model.program_config(conf), model.program_params(arch, SEED),
+        NGramDrafter,
         max_batch=int(serving["max_batch"]),
         controller_factory=CascadeController, clock="wall",
         hw=hardware_for_device_kind(dev.device_kind),

@@ -3,11 +3,14 @@ chip: what the window serves is correct; with the timed path broken
 underneath (a token altered where it is produced, a step that returns its
 cache unchanged), or read through the control (the reference computed
 in the precision below the configuration's), `correct` comes out
-false."""
+false. `moe_in_place_share` on such a run: the cell's 4 rows route 8 or
+more choices over 4 experts, so every pass of its window reads the
+stacked experts in place."""
 
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -48,6 +51,25 @@ def test_sound_run_is_correct():
                                    "setup_s"}
     assert all(m["value"] > 0 for m in out["metrics"].values())
     assert list(out)[-2] == "checks"
+
+
+def test_synthetic_run_reads_experts_in_place(monkeypatch):
+    runs = []
+    real = specs.read_metrics
+
+    def keep(metrics, run, *a, **kw):
+        runs.append(run)
+        return real(metrics, run, *a, **kw)
+
+    monkeypatch.setattr(specs, "read_metrics", keep)
+    out = run()
+    assert out["correct"] is True
+    reader = specs.load_reader("moe_in_place_share")
+    assert runs[0].steps
+    assert reader(runs[0]) == 100.0
+    # a program whose telemetry lacks the field reads nothing
+    old = SimpleNamespace(steps=[SimpleNamespace(packed_experts=4)])
+    assert reader(old) is None
 
 
 def test_control_is_not_correct():

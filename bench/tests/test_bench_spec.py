@@ -6,14 +6,15 @@ import os
 import subprocess
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
+from bench import archs  # noqa: E402
 from bench import spec as specs  # noqa: E402
-from bench.model import arch_of, program_config  # noqa: E402
 from bench.record import RunRecord  # noqa: E402
 
 BENCH = specs.load_json(ROOT / "BENCHMARK.json")
@@ -42,8 +43,9 @@ def test_config_files_state_their_cuts(entry):
     for key, value in conf["published"].items():
         assert conf[key] != value
     # the program runs the widths and semantics the file states
-    program_config(conf)
-    assert arch_of(conf).dtype == "bfloat16"
+    model = archs.for_config(conf)
+    model.program_config(conf)
+    assert model.arch_of(conf).dtype == "bfloat16"
 
 
 def test_a_new_cell_is_files_and_entries(tmp_path):
@@ -84,6 +86,114 @@ def test_a_new_cell_is_files_and_entries(tmp_path):
     assert got == {"steps_per_s": {"value": 2.5, "unit": "steps/s"}}
     with pytest.raises(KeyError):
         specs.load_cell("missing.cell", root=tmp_path, bench_dir=bench)
+
+
+#: a second architecture, as a new file under bench/archs/: its own
+#: sizes, cache and counts, with a leading dense layer
+STUB_ARCH = """
+from dataclasses import dataclass
+
+
+@dataclass(frozen=True)
+class Arch:
+    layers: int
+    dense_layers: int
+    d_model: int
+    latent: int
+    vocab: int
+    dtype: str = "bfloat16"
+    dtype_bytes: int = 2
+
+
+def arch_of(conf):
+    return Arch(conf["num_hidden_layers"], conf["first_k_dense_replace"],
+                conf["hidden_size"], conf["kv_lora_rank"],
+                conf["vocab_size"])
+
+
+def dense_params(a):
+    return a.dense_layers * 3 * a.d_model * 4 * a.d_model + 7
+
+
+def flops_per_token(a, context):
+    return 2.0 * dense_params(a) + context
+
+
+def kv_bytes_per_position(a):
+    return a.layers * a.latent * a.dtype_bytes
+
+
+def pass_bytes(a, tokens, contexts, union_experts_mean):
+    return (dense_params(a) * a.dtype_bytes
+            + kv_bytes_per_position(a) * (sum(contexts) + tokens))
+
+
+def scope_counts(a, tokens, contexts, union_experts_mean):
+    return {"attention": (1.0, kv_bytes_per_position(a) * sum(contexts)),
+            "dense_ffn": (2.0 * tokens * dense_params(a), 0.0)}
+"""
+
+
+def test_a_new_architecture_is_files_and_entries(tmp_path, monkeypatch):
+    """An architecture module and a configuration that names it, added as
+    new files and entries, reach the cell, the counts and the metrics;
+    the harness's architecture-neutral modules name no architecture."""
+    import ast
+    from bench import flops
+    archs_dir = tmp_path / "archs"
+    archs_dir.mkdir()
+    (archs_dir / "stub_mla.py").write_text(STUB_ARCH)
+    monkeypatch.setattr(archs, "__path__", [*archs.__path__, str(archs_dir)])
+    monkeypatch.delitem(sys.modules, "bench.archs.stub_mla", raising=False)
+    bench = tmp_path / "bench"
+    (bench / "configs").mkdir(parents=True)
+    conf = {"name": "stub-mla", "reference": "stub_mla",
+            "num_hidden_layers": 3, "first_k_dense_replace": 1,
+            "hidden_size": 64, "kv_lora_rank": 16, "vocab_size": 100}
+    (bench / "configs" / "stub-mla.json").write_text(json.dumps(conf))
+    spec = {
+        "configs": [{"name": "stub-mla", "source": "x",
+                     "file": "bench/configs/stub-mla.json", "reduced": []}],
+        "workloads": [{"name": "stub.cell", "config": "stub-mla",
+                       "traffic": "agent-mix.c1", "chips": 1, "why": "x"}],
+        "end_to_end": BENCH["end_to_end"], "per_layer": BENCH["per_layer"]}
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    cell = specs.load_cell("stub.cell", root=tmp_path)
+    model = archs.for_config(cell.config)
+    assert model.__name__ == "bench.archs.stub_mla"
+    a = model.arch_of(cell.config)
+    assert archs.of(a) is model
+    # the counts reach the stub: its latent cache, its dense layer
+    assert flops.kv_bytes_per_position(a) == 3 * 16 * 2
+    assert flops.dense_params(a) == 3 * 64 * 256 + 7
+    assert flops.pass_flops(a, 2, [10, 30]) == 2 * (2.0 * 49159 + 20)
+    assert flops.pass_bytes(a, 2, [10, 30], 5.0) == 2 * 49159 + 96 * 42
+    assert flops.scope_counts(a, 2, [10, 30], 5.0)["dense_ffn"] == \
+        (4.0 * 49159, 0.0)
+    # and the metrics that read counts
+    run = RunRecord(arch=a, peaks={"flops_per_s": 1.0, "hbm_bytes_per_s": 1.0},
+                    setup_s=2.0, window_s=4.0, tokens=6, gaps=[], ttfts=[],
+                    steps=[SimpleNamespace(prefill_tokens=0,
+                                           tokens_in_flight=2,
+                                           union_experts=5.0)] * 2,
+                    step_ctx=[[10, 30], [20]], iterations=[], compiles=0)
+    got = specs.read_metrics([{"name": "mfu", "unit": "%"}], run)
+    assert got["mfu"]["value"] == pytest.approx(
+        100 * 6 * (2.0 * 49159 + 20) / 4.0)
+    assert flops.scope_least_time(run, "attention", 2) == 96 * (40 + 20)
+    # no architecture-neutral module names the GQA architecture's module
+    neutral = [p for p in specs.BENCH_DIR.glob("*.py")] + list(
+        (specs.BENCH_DIR / "metrics").glob("*.py"))
+    assert {p.name for p in neutral} >= {"harness.py", "check.py",
+                                         "record.py", "flops.py"}
+    for path in neutral:
+        tree = ast.parse(path.read_text())
+        named = [getattr(n, "module", None) or "" for n in ast.walk(tree)
+                 if isinstance(n, ast.ImportFrom)]
+        named += [a.name for n in ast.walk(tree)
+                  if isinstance(n, (ast.Import, ast.ImportFrom))
+                  for a in n.names]
+        assert not any("moe_decoder" in m for m in named), path
 
 
 def test_a_reader_that_finds_nothing_leaves_its_metric_out():

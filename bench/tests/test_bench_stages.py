@@ -1,10 +1,11 @@
 """The stage reduction (bench/stagereduce.py) on a synthetic trace whose
 program spans, scopes, passes and idle gaps are known by construction,
-and on a few engine steps recorded on a TPU v5e; and the four per-layer
+and on a few engine steps recorded on a TPU v5e; and the six per-layer
 metrics that read it."""
 
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -20,7 +21,7 @@ from bench.record import RunRecord  # noqa: E402
 RECORDED = Path(__file__).parent / "data" / "tpu_v5e_stages.xplane.pb"
 OLD_RECORDED = Path(__file__).parent / "data" / "tpu_v5e_3steps.xplane.pb"
 METRICS = ("pre_pass_host_ms", "post_pass_host_ms", "moe_ffn_ms",
-           "attention_ms")
+           "attention_ms", "moe_ffn_roofline", "attention_roofline")
 
 # ns; the geometry of test_bench_trace.py's synthetic trace, with the
 # program's spans inside its two steps and a layer scope on each operation
@@ -225,10 +226,40 @@ def test_metrics_read_the_newest_trace(synthetic, monkeypatch):
         "moe_ffn_ms": 3e-3, "attention_ms": 2e-3})
 
 
+def test_scope_rooflines_read_the_newest_trace(synthetic, monkeypatch):
+    """Each scope's least time over its self time in the synthetic trace
+    (attention 4 us, moe_ffn 6 us over its two passes), with the tiny
+    configuration's counts for one live token over 10, then 20, cached
+    positions and 2 experts a layer."""
+    monkeypatch.setattr(sr, "TRACES", synthetic.parent)
+    from jax.profiler import ProfileData
+    from bench import archs
+    conf = specs.load_json(Path(__file__).parent / "data" / "tiny-moe.json")
+    run = _run(tr.reduce(ProfileData.from_serialized_xspace(
+        synthetic_bytes())))
+    run.arch = archs.for_config(conf).arch_of(conf)
+    run.peaks = {"flops_per_s": 1e15, "hbm_bytes_per_s": 2e12}
+    run.steps = [SimpleNamespace(tokens_in_flight=1, union_experts=2.0)] * 3
+    run.step_ctx = [[10], [20], [30]]
+    got = {k: v["value"] for k, v in _read(run).items()}
+    # float32; 2 layers of q,k,v,o (196,608) and ln1 (256); 2 layers * 2
+    # KV heads * 64 * 2 (k, v) per position; 2 layers of the router
+    # (256 * 4) and 2 experts of 3 * 256 * 256; bytes bound both
+    attention = 4 * (2 * 2 * (196_608 + 256)) + 2_048 * (11 + 21)
+    moe_ffn = 2 * 4 * 2 * (1_024 + 2 * 196_608)
+    assert got["attention_roofline"] == pytest.approx(
+        100 * attention / 2e12 / 4e-6)
+    assert got["moe_ffn_roofline"] == pytest.approx(
+        100 * moe_ffn / 2e12 / 6e-6)
+    # passes that do not pair with the engine's steps: nothing is read
+    run.steps = run.steps[:1]
+    assert "moe_ffn_roofline" not in _read(run)
+
+
 def test_metrics_read_nothing_without_spans_or_scopes(synthetic,
                                                       monkeypatch):
     """A program without spans or scopes (the trace recorded before they
-    existed), an untraced run, or a trace of another window: the four
+    existed), an untraced run, or a trace of another window: the six
     metrics are left out, and nothing raises."""
     monkeypatch.setattr(sr, "TRACES", OLD_RECORDED)
     assert _read(_run(tr.reduce(tr.load(str(OLD_RECORDED))))) == {}
