@@ -28,10 +28,15 @@ _PAPER = [
     "deepseek_moe_16b",
     "qwen15_moe_a2_7b",
 ]
+#: published MoEs the paper does not evaluate, served by the benchmark
+_SERVED = [
+    "deepseek_v2_lite",
+]
 
 ASSIGNED_ARCHS = [m.replace("_", "-") for m in _ASSIGNED]
 PAPER_ARCHS = [m.replace("_", "-") for m in _PAPER]
-ALL_ARCHS = ASSIGNED_ARCHS + PAPER_ARCHS
+SERVED_ARCHS = [m.replace("_", "-") for m in _SERVED]
+ALL_ARCHS = ASSIGNED_ARCHS + PAPER_ARCHS + SERVED_ARCHS
 
 _REGISTRY: Dict[str, ModelConfig] = {}
 

@@ -784,6 +784,8 @@ def _weight_read_bytes(cfg, precision: Precision) -> float:
                 weights += attn_b  # cross-attention weights
             if cfg.is_moe:
                 weights += shared_b
+        elif k == "D":  # leading dense layer of an MoE: no router, no experts
+            weights += (cfg._attn_params() + cfg._ffn_params(cfg.d_ff)) * wb
         elif k == "R":
             weights += cfg._rglru_layer_params() * wb + ffn_b
             if not ffn_b:  # hybrid is dense-ffn
@@ -812,7 +814,7 @@ def _kv_read_bytes(cfg, context_len: int, window: int,
     window) plus recurrent-state reads."""
     kv_read = 0.0
     for k in cfg.layer_kinds():
-        if k in ("A", "X"):
+        if k in ("A", "D", "X"):
             lw = window
             if cfg.layer_pattern and k == "A":
                 lw = cfg.local_window
@@ -854,7 +856,7 @@ def iteration_flops(cfg, n_tokens: int, context_len: int,
     # attention over the cache
     kinds = cfg.layer_kinds()
     for k in kinds:
-        if k in ("A", "X"):
+        if k in ("A", "D", "X"):
             lw = cfg.local_window if (cfg.layer_pattern and k == "A") else window
             ctx = context_len if not lw else min(context_len, lw)
             hd = cfg.head_dim if not cfg.use_mla else cfg.kv_lora_rank + cfg.qk_rope_dim
@@ -1191,7 +1193,7 @@ def batch_iteration_time(cfg, hw: Hardware, tokens_per_request,
     else:
         experts = _expert_read_bytes(cfg, union, p)
         t_a2a = 0.0
-    n_attn = sum(1 for k in cfg.layer_kinds() if k in ("A", "X"))
+    n_attn = sum(1 for k in cfg.layer_kinds() if k in ("A", "D", "X"))
     prefill_bytes_per_tok = (kv_bytes_per_token(cfg, p.kv) * n_attn
                              + cfg.d_model * p.dense)  # KV write + embed row
     kv_each = [_kv_read_bytes(cfg, c, window, p)
@@ -1387,7 +1389,7 @@ class BatchCostOracle:
             self._replica_groups = (placement.replication_groups
                                     if placement.has_replication else None)
         self._weights = _weight_read_bytes(cfg, p)
-        n_attn = sum(1 for k in cfg.layer_kinds() if k in ("A", "X"))
+        n_attn = sum(1 for k in cfg.layer_kinds() if k in ("A", "D", "X"))
         prefill_bytes_per_tok = (kv_bytes_per_token(cfg, p.kv) * n_attn
                                  + cfg.d_model * p.dense)
         # per-row bytes IF the row is live (n_i > 0); dead rows cost nothing
@@ -1521,7 +1523,7 @@ def prefill_chunk_bytes(cfg, n_tokens: int, context_len: int = 0,
     weights = _weight_read_bytes(cfg, p)
     experts = _expert_read_bytes(cfg, unique_experts or 0.0, p)
     kv_read = _kv_read_bytes(cfg, context_len, window, p)
-    n_attn = sum(1 for k in cfg.layer_kinds() if k in ("A", "X"))
+    n_attn = sum(1 for k in cfg.layer_kinds() if k in ("A", "D", "X"))
     kv_write = n_tokens * kv_bytes_per_token(cfg, p.kv) * n_attn
     embed = n_tokens * cfg.d_model * p.dense  # embedding-row reads per token
     total = weights + experts + kv_read + kv_write + embed

@@ -104,7 +104,8 @@ def _param_rule(path: Tuple[str, ...], shape: Tuple[int, ...],
     """Decide a PartitionSpec for one parameter from its tree path + shape."""
     name = path[-1]
     parent = path[-2] if len(path) >= 2 else ""
-    stacked = "blocks" in path  # leading L dim from the layer-stack vmap
+    # leading L dim from the layer-stack vmap
+    stacked = "blocks" in path or "dense_blocks" in path
     dims = shape[1:] if stacked else shape
     lead = (None,) if stacked else ()
 

@@ -48,13 +48,15 @@ def _split_heads(x, n_heads, head_dim):
     return x.reshape(b, s, n_heads, head_dim)
 
 
-def attend(q, k, v, q_pos, kv_pos, *, window: int = 0, causal: bool = True):
+def attend(q, k, v, q_pos, kv_pos, *, window: int = 0, causal: bool = True,
+           scale=None):
     """Masked GQA attention core.
 
     q: [B,T,H,D]; k,v: [B,S,Hkv,D]
     q_pos: [B,T] absolute positions of queries
     kv_pos: [B,S] absolute positions of keys (-1 marks empty cache slots)
     window: if >0, keys older than q_pos - window are masked (sliding window)
+    scale: the score scale, 1/sqrt(D) where None
     """
     b, t, h, d = q.shape
     hkv = k.shape[2]
@@ -62,7 +64,10 @@ def attend(q, k, v, q_pos, kv_pos, *, window: int = 0, causal: bool = True):
     assert h % hkv == 0, (h, hkv)
     group = h // hkv
 
-    qf = q.astype(jnp.float32) / jnp.sqrt(d).astype(jnp.float32)
+    if scale is None:
+        qf = q.astype(jnp.float32) / jnp.sqrt(d).astype(jnp.float32)
+    else:
+        qf = q.astype(jnp.float32) * scale
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
 

@@ -38,6 +38,8 @@ class ModelConfig:
     num_shared_experts: int = 0
     moe_d_ff: int = 0           # expert intermediate size; 0 -> d_ff
     router_score: str = "softmax"   # "softmax" | "sigmoid" (DeepSeek-V3/Kimi)
+    norm_topk: bool = True      # divide the top-k weights by their sum
+    first_k_dense: int = 0      # leading layers with a dense FFN of width d_ff
 
     # --- MLA (DeepSeek-V2) ---
     use_mla: bool = False
@@ -51,6 +53,13 @@ class ModelConfig:
     rope_variant: str = "standard"  # "standard" | "2d" | "mrope" | "none"
     rope_theta: float = 10000.0
     mrope_sections: Tuple[int, ...] = (16, 24, 24)  # t/h/w split of head_dim//2
+    # YaRN rope scaling (DeepSeek-V2 `rope_scaling`); factor 0 = none
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- hybrid (RecurrentGemma / Griffin) ---
     layer_pattern: str = ""     # e.g. "RRA" repeated; "" = uniform family block
@@ -108,8 +117,13 @@ class ModelConfig:
         return self.d_model // self.rwkv_head_size
 
     def layer_kinds(self) -> Tuple[str, ...]:
-        """Per-layer block kind. 'A' attention+ffn, 'R' recurrent+ffn,
-        'W' rwkv (time-mix + channel-mix), 'X' attention+cross-attn+ffn."""
+        """Per-layer block kind. 'A' attention+ffn (the MoE FFN in an MoE
+        model), 'D' attention+dense ffn ahead of an MoE stack
+        (`first_k_dense`), 'R' recurrent+ffn, 'W' rwkv (time-mix +
+        channel-mix), 'X' attention+cross-attn+ffn."""
+        if self.first_k_dense:
+            k = self.first_k_dense
+            return tuple("D" * k + "A" * (self.num_layers - k))
         if self.layer_pattern:
             pat = self.layer_pattern
             kinds = [pat[i % len(pat)] for i in range(self.num_layers)]
@@ -213,6 +227,7 @@ class ModelConfig:
             encoder_len=min(self.encoder_len, 16),
             encoder_d_model=d_model if self.is_encoder_decoder else 0,
             vision_d_model=d_model if self.vision_stub else 0,
+            first_k_dense=min(self.first_k_dense, 1),  # then an MoE layer
             dtype="float32",
         )
         if self.is_moe:
@@ -223,7 +238,8 @@ class ModelConfig:
                 moe_d_ff=min(self.moe_d_ff, 256),
             )
         if self.use_mla:
-            changes.update(kv_lora_rank=64, q_lora_rank=64,
+            changes.update(kv_lora_rank=64,
+                           q_lora_rank=64 if self.q_lora_rank else 0,
                            qk_nope_dim=32, qk_rope_dim=16, v_head_dim=32,
                            head_dim=32 + 16)
         if self.rope_variant == "mrope":

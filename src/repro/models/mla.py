@@ -9,12 +9,14 @@ MoE verification bottleneck squarely onto the experts (paper §2.4)."""
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from .attention import attend, NEG_INF
 from .layers import _dense_init
-from .rope import apply_rope
+from .rope import apply_rope_scaled, yarn_mscale
 
 
 def init_mla(cfg, key, dtype):
@@ -44,6 +46,15 @@ def _rms(x, scale, eps=1e-6):
     return (out * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def softmax_scale(cfg) -> float:
+    """1/sqrt(qk_nope + qk_rope), times mscale(factor, mscale_all_dim)^2
+    under YaRN (DeepSeek-V2's attention temperature)."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _queries(cfg, p, x, pos2d):
     """-> q_nope [B,T,H,nope], q_rope [B,T,H,rope] (roped)."""
     nope = cfg.qk_nope_dim
@@ -53,14 +64,14 @@ def _queries(cfg, p, x, pos2d):
     else:
         q = jnp.einsum("btd,dhe->bthe", x, p["w_q"])
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, pos2d, cfg.rope_theta)
+    q_rope = apply_rope_scaled(cfg, q_rope, pos2d)
     return q_nope, q_rope
 
 
 def latent_kv(cfg, p, x, pos2d):
     """Compress x -> (c_kv [B,T,R], k_rope [B,T,rope]) — what gets cached."""
     c_kv = _rms(x @ p["w_kva"], p["kv_norm"])
-    k_rope = apply_rope((x @ p["w_kr"])[:, :, None, :], pos2d, cfg.rope_theta)[:, :, 0]
+    k_rope = apply_rope_scaled(cfg, (x @ p["w_kr"])[:, :, None, :], pos2d)[:, :, 0]
     return c_kv, k_rope
 
 
@@ -76,7 +87,8 @@ def mla_full(cfg, p, x, pos2d):
     k_rope_b = jnp.broadcast_to(k_rope[:, :, None, :], (b, t, h, cfg.qk_rope_dim))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, k_rope_b], axis=-1)
-    out = attend(q, k, v, pos2d, pos2d, window=0, causal=True)
+    out = attend(q, k, v, pos2d, pos2d, window=0, causal=True,
+                 scale=softmax_scale(cfg))
     out = out.reshape(b, t, -1) @ p["wo"]
     return out, (c_kv, k_rope)
 
@@ -90,7 +102,7 @@ def mla_absorbed(cfg, p, x, pos2d, ckv_cache, krope_cache, cache_pos,
     cache_pos: [B,R] absolute positions, -1 = empty.
     """
     b, t, _ = x.shape
-    scale = 1.0 / jnp.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = softmax_scale(cfg)
     q_nope, q_rope = _queries(cfg, p, x, pos2d)
     # absorb W_UK into the query: q_lat [B,T,H,R]
     q_lat = jnp.einsum("bthd,lhd->bthl", q_nope.astype(jnp.float32),

@@ -39,16 +39,18 @@ def init_moe(cfg, key, dtype):
 
 
 def route(cfg, p, x2d):
-    """x2d: [T,d] -> (weights [T,k], idx [T,k], probs [T,E])."""
+    """x2d: [T,d] -> (weights [T,k], idx [T,k], probs [T,E]). The weights
+    are the top-k scores, divided by their sum where `cfg.norm_topk`."""
     logits = x2d.astype(jnp.float32) @ p["router"].astype(jnp.float32)
     if cfg.router_score == "sigmoid":        # DeepSeek-V3 / Kimi-K2 style
         scores = jax.nn.sigmoid(logits)
         top, idx = jax.lax.top_k(scores, cfg.experts_per_token)
-        weights = top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
         probs = scores / (jnp.sum(scores, -1, keepdims=True) + 1e-20)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
         top, idx = jax.lax.top_k(probs, cfg.experts_per_token)
+    weights = top
+    if cfg.norm_topk:
         weights = top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
     return weights, idx, probs
 

@@ -7,8 +7,11 @@
 Uniform-kind architectures (dense / moe / ssm / audio / vlm) stack per-layer
 params with a leading L dim and run `lax.scan` over layers, keeping compile
 time O(1) in depth (the 61-layer Kimi-K2 config must compile on one CPU core
-with 512 host devices for the dry-run). The hybrid pattern architecture
-(RecurrentGemma "RRA") uses a python loop over its 38 heterogeneous layers.
+with 512 host devices for the dry-run). An MoE with leading dense layers
+(DeepSeek-V2's `first_k_dense`) stacks and scans those as a group of their
+own (`dense_blocks`, cache entries `dense_*`) ahead of the MoE `blocks`.
+The hybrid pattern architecture (RecurrentGemma "RRA") uses a python loop
+over its 38 heterogeneous layers.
 
 KV caches are ring buffers: ring size = full length for full attention, or
 window + SPEC_PAD for sliding-window variants, so `long_500k` decode on a
@@ -32,6 +35,7 @@ from . import rglru as rglru_mod
 from . import rwkv as rwkv_mod
 
 SPEC_PAD = 16  # ring-buffer slack so speculative writes never clobber window
+DENSE = "dense_"  # cache-entry prefix of the leading dense layers' group
 
 
 # ===================================================================== #
@@ -62,7 +66,7 @@ def _init_block(cfg, kind: str, key, dtype):
         p["lnx"] = L.init_norm(cfg, cfg.d_model, dtype)
         p["xattn"] = attn_mod.init_cross_attention(cfg, ks[1], dtype)
     p["ln2"] = L.init_norm(cfg, cfg.d_model, dtype)
-    if cfg.is_moe:
+    if cfg.is_moe and kind != "D":
         p["moe"] = moe_mod.init_moe(cfg, ks[2], dtype)
     else:
         p["ffn"] = L.init_mlp(cfg, ks[2], cfg.d_model, cfg.d_ff, dtype)
@@ -74,10 +78,14 @@ def init_params(cfg, key):
     kinds = cfg.layer_kinds()
     k_embed, k_blocks = jax.random.split(key)
     params: Dict[str, Any] = {"embed": L.init_embed(cfg, k_embed, dtype)}
-    if len(set(kinds)) == 1:  # uniform: stacked params + scan
+    n_dense = cfg.first_k_dense
+    if len(set(kinds[n_dense:])) == 1:  # uniform: stacked params + scan
         keys = jax.random.split(k_blocks, cfg.num_layers)
+        if n_dense:
+            params["dense_blocks"] = jax.vmap(
+                lambda k: _init_block(cfg, "D", k, dtype))(keys[:n_dense])
         params["blocks"] = jax.vmap(
-            lambda k: _init_block(cfg, kinds[0], k, dtype))(keys)
+            lambda k: _init_block(cfg, kinds[-1], k, dtype))(keys[n_dense:])
     else:
         keys = jax.random.split(k_blocks, cfg.num_layers)
         params["blocks_list"] = tuple(
@@ -118,6 +126,7 @@ def init_cache(cfg, batch: int, max_len: int, *, window: int = 0,
     if per_row:
         cache["lengths"] = jnp.zeros((batch,), jnp.int32)
     n_attn = sum(1 for k in kinds if k in ("A", "X"))
+    n_dense = sum(1 for k in kinds if k == "D")
     n_rec = sum(1 for k in kinds if k == "R")
     n_rwkv = sum(1 for k in kinds if k == "W")
 
@@ -125,13 +134,18 @@ def init_cache(cfg, batch: int, max_len: int, *, window: int = 0,
         w_eff = window if window else (cfg.window or 0)
         r = ring_size(cfg, max_len, w_eff)
         cache["pos"] = jnp.full((batch, r), -1, jnp.int32)
-        if cfg.use_mla:
-            cache["ckv"] = jnp.zeros((n_attn, batch, r, cfg.kv_lora_rank), dtype)
-            cache["krope"] = jnp.zeros((n_attn, batch, r, cfg.qk_rope_dim), dtype)
-        else:
-            hkv, hd = cfg.num_kv_heads, cfg.head_dim
-            cache["k"] = jnp.zeros((n_attn, batch, r, hkv, hd), dtype)
-            cache["v"] = jnp.zeros((n_attn, batch, r, hkv, hd), dtype)
+        for prefix, n in (("", n_attn), (DENSE, n_dense)):
+            if not n:
+                continue
+            if cfg.use_mla:
+                cache[prefix + "ckv"] = jnp.zeros(
+                    (n, batch, r, cfg.kv_lora_rank), dtype)
+                cache[prefix + "krope"] = jnp.zeros(
+                    (n, batch, r, cfg.qk_rope_dim), dtype)
+            else:
+                hkv, hd = cfg.num_kv_heads, cfg.head_dim
+                cache[prefix + "k"] = jnp.zeros((n, batch, r, hkv, hd), dtype)
+                cache[prefix + "v"] = jnp.zeros((n, batch, r, hkv, hd), dtype)
         if cfg.is_encoder_decoder:
             cache["enc_k"] = jnp.zeros(
                 (n_attn, batch, cfg.encoder_len, cfg.num_heads, cfg.head_dim), dtype)
@@ -341,7 +355,7 @@ def _attn_block(cfg, p, x, lc, ctx, kind):
 
     h2 = L.apply_norm(cfg, p["ln2"], x)
     aux = {}
-    if cfg.is_moe:
+    if "moe" in p:
         b, t, d = h2.shape
         with jax.named_scope("moe_ffn"):
             y2d, moe_aux = moe_mod.apply_moe(
@@ -385,7 +399,9 @@ def _attn_block(cfg, p, x, lc, ctx, kind):
                 aux["unique_experts_shard"] = per_shard
                 aux["unique_experts_row_shard"] = row_shard
     else:
-        x = x + L.apply_mlp(cfg, p["ffn"], h2)
+        with jax.named_scope("dense_ffn"):
+            y = L.apply_mlp(cfg, p["ffn"], h2)
+        x = x + y
         aux["lb_loss"] = jnp.zeros((), jnp.float32)
         aux["unique_experts"] = jnp.zeros((), jnp.int32)
         if mode == "decode":
@@ -472,27 +488,22 @@ def _embed_inputs(cfg, params, tokens, embeds, seq_pos):
     return embeds
 
 
-def _layer_cache_slice(cfg, cache, mode):
-    """Split the stacked cache into per-kind stacked dicts for scan xs."""
-    kinds = cfg.layer_kinds()
-    kind = kinds[0]
-    if mode == "train" and kind != "X":
+def _layer_cache_slice(cfg, cache, mode, kind, prefix=""):
+    """The stacked cache entries of one group of `kind` layers (entries
+    named `prefix` + name), keyed by name, for scan xs."""
+    if cache is None or mode == "train":
         return None
     names = {
         "A": ["k", "v"] if not cfg.use_mla else ["ckv", "krope"],
         "X": ["k", "v", "enc_k", "enc_v"],
         "W": ["wkv", "sx_att", "sx_ffn"],
-    }[kind]
-    if mode == "train":
-        return None
-    return {n: cache[n] for n in names if n in cache}
+    }["A" if kind == "D" else kind]
+    return {n: cache[prefix + n] for n in names if prefix + n in cache}
 
 
-def _run_uniform(cfg, params, x, cache, ctx):
+def _run_uniform(cfg, blocks, kind, x, lc_stack, ctx):
     """lax.scan over stacked homogeneous layers."""
-    kind = cfg.layer_kinds()[0]
     mode = ctx["mode"]
-    lc_stack = _layer_cache_slice(cfg, cache, mode) if cache is not None else None
 
     def body(carry, xs):
         h = carry
@@ -515,7 +526,7 @@ def _run_uniform(cfg, params, x, cache, ctx):
 
     if mode == "train":
         body = jax.checkpoint(body)
-    xs = (params["blocks"], lc_stack)
+    xs = (blocks, lc_stack)
     x, ys = jax.lax.scan(body, x, xs)
     return x, ys
 
@@ -626,9 +637,19 @@ def _forward(cfg, params, tokens, *, embeds, cache, mode, seq_pos, rope_pos,
                 new_pos = cache["pos"].at[:, ctx["slots"]].set(
                     seq_pos[:, -t_w:])
             ctx["cache_pos"] = new_pos
-    uniform = len(set(cfg.layer_kinds())) == 1
-    run = _run_uniform if uniform else _run_pattern
-    x, ys = run(cfg, params, x, cache, ctx)
+    dense_cache = {}
+    if "blocks_list" in params:
+        x, ys = _run_pattern(cfg, params, x, cache, ctx)
+    else:
+        if "dense_blocks" in params:
+            x, dys = _run_uniform(
+                cfg, params["dense_blocks"], "D", x,
+                _layer_cache_slice(cfg, cache, mode, "D", DENSE), ctx)
+            dense_cache = {DENSE + n: v
+                           for n, v in dys.get("cache", {}).items()}
+        kind = cfg.layer_kinds()[-1]
+        x, ys = _run_uniform(cfg, params["blocks"], kind, x,
+                             _layer_cache_slice(cfg, cache, mode, kind), ctx)
     with jax.named_scope("lm_head"):
         x = L.apply_norm(cfg, params["final_norm"], x)
         logits = L.unembed(cfg, params["embed"], x)
@@ -654,6 +675,7 @@ def _forward(cfg, params, tokens, *, embeds, cache, mode, seq_pos, rope_pos,
     if cache is not None and mode in ("prefill", "decode"):
         new_cache = dict(cache)
         new_cache.update(ys.get("cache", {}))
+        new_cache.update(dense_cache)
         if "pos" in cache:
             new_cache["pos"] = ctx["cache_pos"]
         if per_row:

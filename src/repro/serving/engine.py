@@ -1142,7 +1142,9 @@ class BatchedEngine:
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(mask))
         with TraceAnnotation("engine.fetch_logits"):
-            lo = np.asarray(lo, np.float32)            # [B, T_max, V]
+            # in the served dtype: the host widens to float32 only the
+            # positions it reads below, not the whole [B, T_max, V] block
+            lo = np.asarray(lo)                        # [B, T_max, V]
             wall_verify = time.perf_counter() - t1
             if not np.asarray(aux["logits_finite"])[mask].all():
                 raise FloatingPointError(
@@ -1165,7 +1167,8 @@ class BatchedEngine:
                     results[i] = greedy_verify(lo[i, :n_i], drafts[i])
                 else:
                     probs = np.asarray(logits_to_probs(
-                        jnp.asarray(lo[i, :n_i]), self.temperature))
+                        jnp.asarray(lo[i, :n_i], jnp.float32),
+                        self.temperature))
                     results[i] = rejection_sample(s.rng, probs, drafts[i],
                                                   draft_probs[i])
                 wall_sample[i] = time.perf_counter() - t2
@@ -1366,8 +1369,9 @@ class BatchedEngine:
                 s.tel.prefill_chunks += 1
                 s.prefill_pos += n
                 if s.prefill_pos >= len(s.prompt):
-                    first = _sample_logits(s.rng, lo[i, n - 1],
-                                           self.temperature)
+                    first = _sample_logits(
+                        s.rng, np.asarray(lo[i, n - 1], np.float32),
+                        self.temperature)
                     s.history.append(first)
                     s.out = [first]
                     s.last_tok = first

@@ -1,8 +1,9 @@
 """Compiles for a described TPU v5e at published widths, with no chip
-attached: the fused MoE kernels, the served 8-layer OLMoE-1B-7B decode
-step (which must fit one chip's HBM), and the expert-parallel MoE layer
-over a 2x2 mesh. What the chip's compiler would refuse (tile alignment,
-VMEM limits, a program too large for HBM) fails here. Nothing runs.
+attached: the fused MoE kernels, the served 8-layer OLMoE-1B-7B and
+DeepSeek-V2-Lite decode steps (which must fit one chip's HBM), and the
+expert-parallel MoE layer over a 2x2 mesh. What the chip's compiler
+would refuse (tile alignment, VMEM limits, a program too large for HBM)
+fails here. Nothing runs.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -27,6 +28,7 @@ from repro.models import moe as moe_mod
 from repro.models import transformer as T
 
 OLMOE = get_config("olmoe-1b-7b")
+DSV2_LITE = get_config("deepseek-v2-lite")
 HBM_BUDGET = 15 * 2 ** 30   # one v5e chip's 16 GiB, less 1 GiB of headroom
 
 # (U packed expert slots, C tokens per slot, d_model, expert width F)
@@ -125,6 +127,35 @@ def test_served_decode_step_fits_one_chip(one_chip, t):
     stacks = _defined_with_shape(compiled.as_text(), [(e, d, f), (e, f, d)])
     assert len(stacks) <= 3, stacks
     assert mem.temp_size_in_bytes < 0.3e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("t", [1, 32])
+def test_served_deepseek_v2_lite_decode_step_fits_one_chip(one_chip, t):
+    """The pass the serving path runs at published DeepSeek-V2-Lite
+    widths, depth cut to 8 layers (the dense layer 0 and 7 MoE layers):
+    batch 8, t-token spans, a 2048-position latent cache per row,
+    union-packed MoE. Its MoE layers read their experts in place, as
+    OLMoE's do, and the latent cache is two stacks per layer group."""
+    cfg = dataclasses.replace(DSV2_LITE, num_layers=8)
+    assert moe_mod.packed_expert_cap(cfg, 8 * t) == cfg.num_experts
+    params = _on(one_chip, jax.eval_shape(
+        functools.partial(T.init_params, cfg), jax.random.PRNGKey(0)))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: T.init_cache(cfg, 8, 2048, per_row=True)))
+    assert cache["ckv"].shape == (7, 8, 2048, 512)
+    assert cache["dense_krope"].shape == (1, 8, 2048, 64)
+    toks = jax.ShapeDtypeStruct((8, t), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((8, t), jnp.bool_, sharding=one_chip)
+    step = jax.jit(lambda p, c, tk, m: T.decode_step(
+        cfg, p, c, tk, token_mask=m, moe_packed=True))
+    compiled = step.lower(params, cache, toks, mask).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BUDGET, total
+    e, d, f = cfg.num_experts, cfg.d_model, cfg.moe_d_ff
+    stacks = _defined_with_shape(compiled.as_text(), [(e, d, f), (e, f, d)])
+    assert len(stacks) <= 3, stacks
 
 
 def test_expert_parallel_moe_compiles_on_four_chips(topo):

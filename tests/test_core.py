@@ -251,6 +251,27 @@ def test_iteration_bytes_mla_cache_small():
         32768 * (512 + 64) * 2 * ds.num_layers, rel=0.01)
 
 
+def test_iteration_bytes_price_a_dense_prefix_as_dense():
+    """The 8-layer DeepSeek-V2-Lite: layer 0's dense FFN is read every
+    pass; routed experts are priced in the 7 MoE layers only, shared
+    experts and routers with them; every layer writes and reads its
+    latent."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), num_layers=8)
+    b = iteration_bytes(cfg, 1, 100, unique_experts=6.0)
+    d, wb = 2048, 2
+    attn = cfg._attn_params()
+    assert b["weights"] == (8 * attn + 3 * d * 10944 + 7 * (
+        d * 64 + 2 * 3 * d * 1408) + 102400 * d) * wb
+    assert b["experts"] == 7 * 6 * 3 * d * 1408 * wb
+    assert b["kv"] == 8 * 100 * (512 + 64) * wb
+    # the layer axis of the MoE aux counts, residency and fetch schedule
+    from repro.core.cost_model import moe_hide_fracs
+    from repro.core.residency import moe_layer_count
+    assert moe_layer_count(cfg) == 7
+    assert moe_hide_fracs(cfg) == [(i + 0.5) / 8 for i in range(1, 8)]
+
+
 def test_cost_model_k_prior():
     """Beyond-paper: the analytic K prior must be conservative for
     low-affinity MoEs and aggressive for dense models."""

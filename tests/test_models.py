@@ -2,6 +2,7 @@
 equivalence — the correctness bedrock for speculative verification."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,7 @@ def test_smoke_forward_and_train_step(arch, key):
     "whisper-large-v3",      # enc-dec
     "chatglm3-6b",           # dense GQA + 2d rope
     "qwen2-vl-7b",           # VLM / M-RoPE
+    "deepseek-v2-lite",      # dense layer 0, YaRN MLA, top-k unrenormalised
 ])
 def test_decode_matches_full_forward_and_rollback(arch, key):
     cfg = get_config(arch).reduced()
@@ -109,6 +111,132 @@ def test_moe_unique_expert_telemetry(tiny_moe, key):
     assert u.shape == (cfg.num_layers,)
     assert (u >= cfg.experts_per_token).all()
     assert (u <= cfg.num_experts).all()
+
+
+def test_reduced_keeps_the_configs_mechanisms():
+    """The CPU-sized DeepSeek-V2-Lite keeps a dense layer before an MoE
+    layer, no query LoRA, YaRN and un-renormalised top-k; a config with a
+    query LoRA keeps one."""
+    cfg = get_config("deepseek-v2-lite").reduced()
+    assert cfg.layer_kinds() == ("D", "A")
+    assert cfg.q_lora_rank == 0 and cfg.use_mla
+    assert cfg.yarn_factor == 40.0 and cfg.yarn_mscale_all_dim == 0.707
+    assert cfg.norm_topk is False and cfg.num_shared_experts == 1
+    params = jax.eval_shape(functools.partial(T.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    assert "ffn" in params["dense_blocks"] and "moe" in params["blocks"]
+    assert "w_q" in params["blocks"]["attn"]
+    assert get_config("deepseek-v2-236b").reduced().q_lora_rank == 64
+
+
+def test_deepseek_v2_lite_counts_its_published_total():
+    """15.7 B parameters (the model card), within 2%: the dense layer is
+    priced as dense, the 26 MoE layers with 64 experts and 2 shared."""
+    cfg = get_config("deepseek-v2-lite")
+    assert cfg.param_count() == pytest.approx(15.7e9, rel=0.02)
+    dense = cfg._attn_params() + cfg._ffn_params(cfg.d_ff)
+    moe = (cfg._attn_params() + 66 * cfg._ffn_params(cfg.moe_d_ff)
+           + cfg.d_model * cfg.num_experts)
+    assert cfg.param_count() == dense + 26 * moe + 2 * 102400 * 2048
+
+
+def test_yarn_frequencies_and_score_scale():
+    """DeepSeek-V2's YaRN at its published settings (rope dim 64, factor
+    40 over 4096 positions, beta 32 and 1, mscale 0.707): the frequencies
+    from the published formula, written out here, and the MLA score scale
+    1/sqrt(192) * (0.1 * 0.707 * ln 40 + 1)^2."""
+    import math
+    from repro.models import mla as mla_mod
+    from repro.models import rope as rope_mod
+    dim, base, factor, orig = 64, 10000.0, 40.0, 4096
+
+    def corr_dim(rot):
+        return (dim * math.log(orig / (rot * 2 * math.pi))
+                / (2 * math.log(base)))
+    low, high = math.floor(corr_dim(32)), math.ceil(corr_dim(1))
+    assert (low, high) == (10, 23)
+    want = []
+    for i in range(dim // 2):
+        extra = base ** (-2 * i / dim)
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        want.append(extra / factor * ramp + extra * (1 - ramp))
+    got = rope_mod.yarn_inv_freq(dim, base, factor, orig, 32.0, 1.0)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert got[10] == pytest.approx(base ** (-20 / 64), rel=1e-6)
+    assert got[23] == pytest.approx(base ** (-46 / 64) / 40, rel=1e-6)
+    cfg = get_config("deepseek-v2-lite")
+    assert (mla_mod.softmax_scale(cfg) * math.sqrt(192)
+            == pytest.approx(1.5896, abs=1e-4))
+    # mscale / mscale_all_dim = 1: cos and sin are not rescaled here
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 5, 2, 64))
+    pos = jnp.arange(5)[None]
+    yarn = rope_mod.apply_rope_scaled(cfg, x, pos)
+    plain = rope_mod.apply_rope(x, pos, inv_freq=jnp.asarray(got))
+    np.testing.assert_allclose(np.asarray(yarn), np.asarray(plain),
+                               rtol=1e-6)
+    half = dataclasses.replace(cfg, yarn_mscale=2 * 0.707)
+    ratio = (rope_mod.yarn_mscale(40.0, 1.414)
+             / rope_mod.yarn_mscale(40.0, 0.707))
+    np.testing.assert_allclose(
+        np.asarray(rope_mod.apply_rope_scaled(half, x, pos)),
+        ratio * np.asarray(plain), rtol=1e-5)
+
+
+@pytest.mark.parametrize("norm_topk", [True, False])
+def test_route_renormalises_only_where_configured(norm_topk):
+    from repro.models import moe as moe_mod
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite").reduced(),
+                              norm_topk=norm_topk)
+    kr, kx = jax.random.split(jax.random.PRNGKey(3))
+    p = {"router": jax.random.normal(kr, (cfg.d_model, cfg.num_experts))
+         / np.sqrt(cfg.d_model)}
+    x = jax.random.normal(kx, (5, cfg.d_model))
+    weights, idx, probs = moe_mod.route(cfg, p, x)
+    raw = jax.nn.softmax(x @ p["router"], axis=-1)
+    top = np.take_along_axis(np.asarray(raw), np.asarray(idx), axis=-1)
+    np.testing.assert_allclose(np.asarray(probs), np.asarray(raw),
+                               rtol=1e-5)
+    want = top / top.sum(-1, keepdims=True) if norm_topk else top
+    np.testing.assert_allclose(np.asarray(weights), want, rtol=1e-5)
+    assert (top.sum(-1) < 1.0).all()
+
+
+#: sha256 of the jaxprs of the reduced OLMoE's served programs, as they
+#: were before `first_k_dense`, `norm_topk` and the YaRN fields existed;
+#: a change to what OLMoE's served pass computes changes them
+OLMOE_PROGRAMS = {
+    "decode_1": ("fa64285318955281fbca1fe64b6d3108"
+                 "bd707dd53e012afbf3d17e06a2007564"),
+    "decode_8": ("fd70b721b5dc5050ab4c366e101ef6f5"
+                 "8ddce5e0a20f5ae200a6271cbe1b1ed5"),
+    "prefill": ("a5773d951e29bfe852505cfb3857ef66"
+                "89c5fa4cf8c60560899770d7d15b819a"),
+}
+
+
+def test_olmoe_programs_unchanged_by_the_new_fields_defaults():
+    """The served decode step (packed MoE, per-row cache, 1- and 8-token
+    spans) and the blocking prefill of an OLMoE-shaped config trace to the
+    same jaxprs as before the dense prefix, `norm_topk` and YaRN."""
+    import hashlib
+    cfg = get_config("olmoe-1b-7b").reduced()
+    assert (cfg.first_k_dense, cfg.norm_topk, cfg.yarn_factor) == (0, True, 0)
+    params = jax.eval_shape(functools.partial(T.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: T.init_cache(cfg, 2, 64, per_row=True))
+    got = {}
+    for t in (1, 8):
+        toks = jax.ShapeDtypeStruct((2, t), jnp.int32)
+        mask = jax.ShapeDtypeStruct((2, t), jnp.bool_)
+        got[f"decode_{t}"] = jax.make_jaxpr(
+            lambda p, c, tk, m: T.decode_step(cfg, p, c, tk, token_mask=m,
+                                              moe_packed=True))(
+            params, cache, toks, mask)
+    toks = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+    got["prefill"] = jax.make_jaxpr(
+        lambda p, tk, c: T.prefill(cfg, p, tk, c))(params, toks, cache)
+    assert {k: hashlib.sha256(str(v).encode()).hexdigest()
+            for k, v in got.items()} == OLMOE_PROGRAMS
 
 
 def test_param_counts_sane():
