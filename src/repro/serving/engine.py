@@ -151,13 +151,44 @@ def _hidden_router_probe(cfg, params, moe_h, mask):
     return _layer_hist(cfg, idx, mask)
 
 
-def _with_finite_flags(out):
-    """decode_step output plus `aux["logits_finite"]` [B,T]: whether each
-    token's logits are all finite, reduced on the device so the host checks
-    B*T flags instead of scanning [B,T,V] logits every step."""
+def _argmax_and_finite(lo):
+    """Each position's argmax over V (int32, the first index on ties, as
+    `np.argmax`) and whether all its logits are finite, from one variadic
+    reduction: one read of the [B,T,V] logits for both."""
+    v_axis = lo.ndim - 1
+    idx = jax.lax.broadcasted_iota(jnp.int32, lo.shape, v_axis)
+
+    def pick(a, b):
+        a_hi, a_i, a_ok = a
+        b_hi, b_i, b_ok = b
+        take_b = (b_hi > a_hi) | ((b_hi == a_hi) & (b_i < a_i))
+        return (jnp.where(take_b, b_hi, a_hi), jnp.where(take_b, b_i, a_i),
+                a_ok & b_ok)
+
+    init = (jnp.array(-jnp.inf, lo.dtype), jnp.array(0, jnp.int32),
+            jnp.array(True))
+    _, ids, finite = jax.lax.reduce((lo, idx, jnp.isfinite(lo)), init, pick,
+                                    (v_axis,))
+    return ids, finite
+
+
+def _with_host_reads(out):
+    """decode_step output plus what the host reads of the logits, reduced
+    on the device: `aux["greedy_ids"]` [B,T] (each position's argmax token)
+    and `aux["logits_finite"]` [B,T] (whether its logits are all finite).
+    A greedy step then copies B*T ids and flags to the host, not the
+    [B,T,V] logits."""
     lo, cache, aux, staged = out
-    return (lo, cache, dict(aux, logits_finite=jnp.isfinite(lo).all(-1)),
-            staged)
+    with jax.named_scope("lm_head"):
+        ids, finite = _argmax_and_finite(lo)
+    return lo, cache, dict(aux, greedy_ids=ids, logits_finite=finite), staged
+
+
+#: aux entries the host reads after every pass (`experts_active` too with
+#: host-tier experts; `moe_h` stays on the device)
+_HOST_AUX = ("greedy_ids", "logits_finite", "unique_experts",
+             "unique_experts_row", "unique_experts_shard",
+             "unique_experts_row_shard")
 
 
 def _prefill_clock(cfg, hw, clock: str, n_tokens: int, wall: float, *,
@@ -631,7 +662,7 @@ class BatchedEngine:
                 self.placement.primary_shard_of, np.int32)
             n_sh = self.placement.n_shards
             self._decode = jax.jit(
-                lambda p, c, t, m, sid: _with_finite_flags(T.decode_step(
+                lambda p, c, t, m, sid: _with_host_reads(T.decode_step(
                     cfg, p, c, t, window=window, token_mask=m,
                     ep_shard_ids=sid, ep_n_shards=n_sh,
                     moe_packed=self.packed, want_moe_h=want_h)))
@@ -640,7 +671,7 @@ class BatchedEngine:
             sid = (tuple(self.placement.primary_shard_of)
                    if self._ep else None)
             self._decode = jax.jit(
-                lambda p, c, t, m: _with_finite_flags(T.decode_step(
+                lambda p, c, t, m: _with_host_reads(T.decode_step(
                     cfg, p, c, t, window=window, token_mask=m,
                     ep_shard_ids=sid, moe_packed=self.packed,
                     want_moe_h=want_h)))
@@ -1142,11 +1173,20 @@ class BatchedEngine:
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(mask))
         with TraceAnnotation("engine.fetch_logits"):
-            # in the served dtype: the host widens to float32 only the
-            # positions it reads below, not the whole [B, T_max, V] block
-            lo = np.asarray(lo)                        # [B, T_max, V]
+            # one copy of what the host reads: [B, T_max] greedy ids, finite
+            # flags and the counters; the [B, T_max, V] logits, in the served
+            # dtype, only where rejection sampling needs them
+            keys = _HOST_AUX + (("experts_active",) if self._offload else ())
+            want = {k: aux[k] for k in keys if k in aux}
+            if self.temperature > 0:
+                want["logits"] = lo
+            host = jax.device_get(want)
             wall_verify = time.perf_counter() - t1
-            if not np.asarray(aux["logits_finite"])[mask].all():
+            d2h_bytes = sum(a.nbytes for a in host.values())
+            lo = host.pop("logits", None)              # [B, T_max, V] | None
+            aux = dict(aux, **host)
+            greedy_ids = aux["greedy_ids"]             # [B, T_max]
+            if not aux["logits_finite"][mask].all():
                 raise FloatingPointError(
                     f"non-finite logits in the pass of step {self._step_idx}")
             if self._hprobe is not None and "moe_h" in aux:
@@ -1164,7 +1204,7 @@ class BatchedEngine:
                 n_i = 1 + len(drafts[i])
                 t2 = time.perf_counter()
                 if self.temperature <= 0:
-                    results[i] = greedy_verify(lo[i, :n_i], drafts[i])
+                    results[i] = greedy_verify(greedy_ids[i, :n_i], drafts[i])
                 else:
                     probs = np.asarray(logits_to_probs(
                         jnp.asarray(lo[i, :n_i], jnp.float32),
@@ -1369,9 +1409,10 @@ class BatchedEngine:
                 s.tel.prefill_chunks += 1
                 s.prefill_pos += n
                 if s.prefill_pos >= len(s.prompt):
-                    first = _sample_logits(
-                        s.rng, np.asarray(lo[i, n - 1], np.float32),
-                        self.temperature)
+                    first = (int(greedy_ids[i, n - 1])
+                             if self.temperature <= 0 else _sample_logits(
+                                 s.rng, np.asarray(lo[i, n - 1], np.float32),
+                                 self.temperature))
                     s.history.append(first)
                     s.out = [first]
                     s.last_tok = first
@@ -1427,7 +1468,8 @@ class BatchedEngine:
                 prefetch_hits_by_layer=hit_by_layer,
                 prefetch_misses_by_layer=miss_by_layer,
                 precision=cost.get("precision", ""),
-                expert_bytes_saved=cost.get("expert_bytes_saved", 0.0))
+                expert_bytes_saved=cost.get("expert_bytes_saved", 0.0),
+                d2h_bytes=d2h_bytes)
             self.telemetry.steps.append(step_tel)
             # every decode row experienced the WHOLE pass between its
             # tokens — the latency quantity SLOs bound (vs t_iter's

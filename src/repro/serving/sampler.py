@@ -80,11 +80,17 @@ def rejection_sample(rng: np.random.Generator,
     return RejectionResult(accepted, tok, len(accepted))
 
 
-def greedy_verify(target_logits: np.ndarray, draft_tokens: List[int]
+def greedy_verify(target: np.ndarray, draft_tokens: List[int]
                   ) -> RejectionResult:
     """Deterministic verification: accept drafts while they match the target
-    argmax; emit the first mismatching argmax (or the bonus argmax)."""
-    argmaxes = np.argmax(np.asarray(target_logits, np.float32), axis=-1)
+    argmax; emit the first mismatching argmax (or the bonus argmax).
+    `target` is either the [K+1, V] logits or the [K+1] integer argmax ids
+    already picked from them (on the device, as `BatchedEngine` does)."""
+    target = np.asarray(target)
+    if target.ndim == 1 and np.issubdtype(target.dtype, np.integer):
+        argmaxes = target
+    else:
+        argmaxes = np.argmax(target.astype(np.float32), axis=-1)
     accepted: List[int] = []
     for i, d in enumerate(draft_tokens):
         if int(argmaxes[i]) == int(d):

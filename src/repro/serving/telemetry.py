@@ -111,6 +111,9 @@ class StepTelemetry:
     precision: str = ""        # cost-model Precision label ("" = legacy)
     expert_bytes_saved: float = 0.0  # expert-read bytes this pass avoided
                                # moving vs bf16 storage (0.0 unquantized)
+    d2h_bytes: int = 0         # bytes copied from the device to the host
+                               # after the pass (greedy: ids, flags and
+                               # counters; sampled: also the logits)
 
     @property
     def t_total(self) -> float:

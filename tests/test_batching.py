@@ -330,3 +330,104 @@ def test_step_telemetry_reports_experts_in_place(tiny_moe):
 
     assert all(steps(8, 2))
     assert not any(steps(1, 0))
+
+
+# ===================================================================== #
+# Greedy tokens picked on the device: the host copies [B, T] ids, not
+# the [B, T, V] logits
+# ===================================================================== #
+
+@pytest.fixture(scope="module")
+def tiny_dsv2():
+    """A reduced DeepSeek-V2-Lite: MLA, a dense layer 0, YaRN, shared
+    experts and un-renormalised top-k."""
+    cfg = get_config("deepseek-v2-lite").reduced()
+    return cfg, T.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _two_requests():
+    # prompts of 19 and 27 tokens: three and four chunks of up to 8
+    return [Request(request_id=f"r{i}", prompt=[3 + i, 5, 7 + i] * (6 + 3 * i)
+                    + [11], max_new=14) for i in range(2)]
+
+
+def _record_passes(eng):
+    """Keep each pass's logits beside the ids the pass picked from them."""
+    passes = []
+    real = eng._decode
+
+    def decode(*args):
+        out = real(*args)
+        passes.append((np.asarray(out[0], np.float32),
+                       np.asarray(out[2]["greedy_ids"])))
+        return out
+
+    eng._decode = decode
+    return passes
+
+
+@pytest.mark.parametrize("model", ["tiny_moe", "tiny_dsv2"])
+def test_device_greedy_streams_equal_host_logits_path(model, request):
+    """Across chunked prefill and speculative decode, every pass's device
+    ids are the host's argmax of that pass's logits, and each greedy
+    stream equals the legacy engine's at B = 1, which picks its tokens
+    from the logits on the host."""
+    cfg, params = request.getfixturevalue(model)
+    eng = BatchedEngine(cfg, params, lambda: NGramDrafter(), max_batch=2,
+                        max_len=256, temperature=0.0, clock="model",
+                        chunk=8, packed=True)
+    passes = _record_passes(eng)
+    res = ContinuousBatchingScheduler(
+        eng, controller_factory=lambda: StaticKController(2)).run(
+        _two_requests())
+    assert {lo.shape[1] for lo, _ in passes} >= {8}
+    assert any(s.prefill_tokens and s.decode_tokens
+               for s in eng.telemetry.steps)
+    for lo, ids in passes:
+        assert ids.dtype == np.int32
+        np.testing.assert_array_equal(ids, np.argmax(lo, axis=-1))
+    leg = ServingEngine(cfg, params, NGramDrafter(), max_len=256,
+                        temperature=0.0, clock="model")
+    for q, r in zip(_two_requests(), sorted(
+            res, key=lambda r: r.telemetry.request_id)):
+        ref = leg.generate(q.prompt, q.max_new,
+                           controller=StaticKController(2))
+        assert r.tokens == ref.tokens, q.request_id
+
+
+@pytest.mark.parametrize("temperature", [0.0, 1.0])
+def test_fetch_copies_logits_only_when_sampling(tiny_moe, monkeypatch,
+                                                temperature):
+    """A greedy step copies ids, flags and counters, with no vocabulary
+    axis; a sampled step still copies the logits and rejection-samples."""
+    from repro.serving import engine as engine_mod
+    cfg, params = tiny_moe
+    calls = {"greedy_verify": 0, "rejection_sample": 0}
+    for name in calls:
+        real = getattr(engine_mod, name)
+
+        def counted(*a, _real=real, _name=name):
+            calls[_name] += 1
+            return _real(*a)
+
+        monkeypatch.setattr(engine_mod, name, counted)
+    b = 2
+    eng = BatchedEngine(cfg, params, lambda: NGramDrafter(), max_batch=b,
+                        max_len=256, temperature=temperature, clock="model",
+                        chunk=8, packed=True, seed=3)
+    res = ContinuousBatchingScheduler(
+        eng, controller_factory=lambda: StaticKController(2)).run(
+        _two_requests())
+    assert all(len(r.tokens) == 14 for r in res)
+    for s in eng.telemetry.steps:
+        t_max = (s.tokens_in_flight + s.padded_tokens) // s.occupancy
+        logits_bytes = b * t_max * cfg.vocab_size * 4      # float32 here
+        if temperature > 0:
+            assert s.d2h_bytes > logits_bytes
+        else:
+            # int32 ids and bool flags [B, T]; int32 unions [L] and [L, B]
+            assert s.d2h_bytes == (b * t_max * (4 + 1)
+                                   + cfg.num_layers * (1 + b) * 4)
+    sampled = temperature > 0
+    assert (calls["rejection_sample"] > 0) == sampled
+    assert (calls["greedy_verify"] > 0) != sampled

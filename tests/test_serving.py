@@ -79,6 +79,47 @@ def test_greedy_verify_matches_argmax():
     assert res2.n_accepted == 3 and res2.next_token == 1  # bonus row
 
 
+@pytest.mark.parametrize("drafts", [
+    [],            # K = 0: the bonus token alone
+    [1, 0, 2],     # all accepted
+    [2, 0, 2],     # first rejected
+    [1, 1, 2],     # rejected in the middle
+], ids=["k0", "all-accepted", "first-rejected", "middle-rejected"])
+def test_greedy_verify_on_ids_equals_on_logits(drafts):
+    """The engine verifies on the [K+1] argmax ids it picked on the device;
+    that must give what the [K+1, V] logits give, ties included (rows 0
+    and 3 hold tied maxima, and the first index wins)."""
+    logits = np.array([[0, 3, 3], [5, 0, 5], [0, 0, 9], [1, 2, 2]],
+                      np.float32)[:len(drafts) + 1]
+    ids = np.argmax(logits, axis=-1).astype(np.int32)
+    assert ids[0] == 1                   # the tie of 1 and 2 goes to 1
+    on_logits = greedy_verify(logits, drafts)
+    on_ids = greedy_verify(ids, drafts)
+    assert on_ids == on_logits
+    assert on_ids.accepted == drafts[:on_ids.n_accepted]
+
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_device_argmax_and_finite_flags(dtype):
+    """The pass's one reduction over V gives `np.argmax` of the logits
+    widened to float32 (first index on ties, which bfloat16 makes common)
+    and flags a position holding NaN or an infinity."""
+    from repro.serving.engine import _argmax_and_finite
+    x = np.random.default_rng(0).standard_normal((3, 4, 300)).astype(
+        np.float32)
+    x[0, 0, [7, 100]] = 50.0
+    x[1, 1, 3], x[2, 2, 4], x[2, 3, 9] = np.nan, -np.inf, np.inf
+    lo = jnp.asarray(x, dtype)
+    ids, finite = jax.jit(_argmax_and_finite)(lo)
+    wide = np.asarray(lo.astype(jnp.float32))
+    ok = np.isfinite(wide).all(-1)
+    assert ok.sum() == 9 and ids.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(finite), ok)
+    np.testing.assert_array_equal(np.asarray(ids)[ok],
+                                  np.argmax(wide, axis=-1)[ok])
+    assert int(ids[0, 0]) == 7
+
 # ===================================================================== #
 # N-gram drafter
 # ===================================================================== #
